@@ -51,15 +51,13 @@ def _verify_attn_kernel(qpos_ref, kvpos_ref, q_ref, k_ref, v_ref,  # inputs
     q = q_ref[0, 0].astype(jnp.float32)            # (RQ = kq*G, hd)
     k = k_ref[0, 0].astype(jnp.float32)            # (block_kv, hd)
     v = v_ref[0, 0].astype(jnp.float32)            # (block_kv, hd)
-    qpos = qpos_ref[0]                             # (RQ,) int32 (row -> q pos)
-    kvpos = kvpos_ref[0]                           # (block_kv,) int32
+    qp = qpos_ref[0]                               # (RQ, 1) int32 (row -> q pos)
+    kp = kvpos_ref[0]                              # (1, block_kv) int32
 
     scores = jax.lax.dot_general(
         q * scale, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)        # (RQ, block_kv)
 
-    qp = qpos[:, None]
-    kp = kvpos[None, :]
     mask = (kp >= 0) & (kp <= qp)
     if window:
         mask &= (qp - kp < window) | (kp < num_meta)
@@ -78,6 +76,23 @@ def _verify_attn_kernel(qpos_ref, kvpos_ref, q_ref, k_ref, v_ref,  # inputs
     def _finish():
         o_ref[0, 0] = (acc_ref[...]
                        / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+
+
+def _rows(x, g, rq_pad, fill):
+    """(B, kq) per-query values -> (B, rq_pad, 1) per kernel row (row =
+    q_idx * g + g_idx): a column block, so the kernel reads it (RQ, 1)."""
+    rows = jnp.repeat(x.astype(jnp.int32), g, axis=1)
+    rows = jnp.pad(rows, ((0, 0), (0, rq_pad - rows.shape[1])),
+                   constant_values=fill)
+    return rows[:, :, None]
+
+
+def _cols(x, l_pad):
+    """(B, L) per-slot values -> (B, 1, l_pad), padded slots -1 (masked):
+    a row block, so the kernel reads it (1, block_kv)."""
+    x = x.astype(jnp.int32)
+    return jnp.pad(x, ((0, 0), (0, l_pad - x.shape[1])),
+                   constant_values=-1)[:, None, :]
 
 
 def verify_attention_pallas(q, k, v, q_pos, kv_pos, *, window: int = 0,
@@ -108,10 +123,8 @@ def verify_attention_pallas(q, k, v, q_pos, kv_pos, *, window: int = 0,
                  ((0, 0), (0, 0), (0, l_pad - l), (0, hd_pad - hd)))
 
     # per-row query positions (row = q_idx * g + g_idx)
-    qpos_rows = jnp.repeat(q_pos, g, axis=1)                     # (B, rq)
-    qpos_rows = jnp.pad(qpos_rows, ((0, 0), (0, rq_pad - rq)),
-                        constant_values=-(2 ** 30))
-    kvpos_p = jnp.pad(kv_pos, ((0, 0), (0, l_pad - l)), constant_values=-1)
+    qpos_rows = _rows(q_pos, g, rq_pad, -(2 ** 30))             # (B, rq, 1)
+    kvpos_p = _cols(kv_pos, l_pad)                              # (B, 1, L)
 
     grid = (b, kvh, l_pad // block_kv)
     out = pl.pallas_call(
@@ -119,8 +132,8 @@ def verify_attention_pallas(q, k, v, q_pos, kv_pos, *, window: int = 0,
                           num_meta=num_meta, scale=scale),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, rq_pad), lambda bi, hi, ki: (bi, 0)),
-            pl.BlockSpec((1, block_kv), lambda bi, hi, ki: (bi, ki)),
+            pl.BlockSpec((1, rq_pad, 1), lambda bi, hi, ki: (bi, 0, 0)),
+            pl.BlockSpec((1, 1, block_kv), lambda bi, hi, ki: (bi, 0, ki)),
             pl.BlockSpec((1, 1, rq_pad, hd_pad), lambda bi, hi, ki: (bi, hi, 0, 0)),
             pl.BlockSpec((1, 1, block_kv, hd_pad), lambda bi, hi, ki: (bi, hi, ki, 0)),
             pl.BlockSpec((1, 1, block_kv, hd_pad), lambda bi, hi, ki: (bi, hi, ki, 0)),
@@ -170,25 +183,21 @@ def _tree_verify_attn_kernel(qpos_ref, abits_ref, kvpos_ref, kvnode_ref,
     q = q_ref[0, 0].astype(jnp.float32)            # (RQ = kq*G, hd)
     k = k_ref[0, 0].astype(jnp.float32)            # (block_kv, hd)
     v = v_ref[0, 0].astype(jnp.float32)            # (block_kv, hd)
-    qpos = qpos_ref[0]                             # (RQ,) int32 logical pos
-    abits = abits_ref[0]                           # (RQ,) int32 ancestor bits
-    kvpos = kvpos_ref[0]                           # (block_kv,) int32
-    kvnode = kvnode_ref[0]                         # (block_kv,) int32 (-1=prefix)
+    qp = qpos_ref[0]                               # (RQ, 1) int32 logical pos
+    abits = abits_ref[0]                           # (RQ, 1) int32 ancestor bits
+    kp = kvpos_ref[0]                              # (1, block_kv) int32
+    kn = kvnode_ref[0]                             # (1, block_kv) int32 (-1=prefix)
 
     scores = jax.lax.dot_general(
         q * scale, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)        # (RQ, block_kv)
 
-    qp = qpos[:, None]
-    kp = kvpos[None, :]
-    kn = kvnode[None, :]
     mask = (kp >= 0) & (kp <= qp)
     if window:
         mask &= (qp - kp < window) | (kp < num_meta)
     # tree slots additionally require the ancestor bit; ancestors sit at
     # shallower depth so (kp <= qp) already holds for every visible one
-    bit = jax.lax.shift_right_logical(
-        abits[:, None], jnp.clip(kn, 0, 31)) & 1
+    bit = jax.lax.shift_right_logical(abits, jnp.clip(kn, 0, 31)) & 1
     mask &= (kn < 0) | (bit != 0)
     scores = jnp.where(mask, scores, NEG_INF)
 
@@ -241,14 +250,10 @@ def tree_verify_attention_pallas(q, k, v, q_pos, kv_pos, kv_node, anc_bits, *,
     vr = jnp.pad(v.transpose(0, 2, 1, 3),
                  ((0, 0), (0, 0), (0, l_pad - l), (0, hd_pad - hd)))
 
-    qpos_rows = jnp.repeat(q_pos, g, axis=1)                     # (B, rq)
-    qpos_rows = jnp.pad(qpos_rows, ((0, 0), (0, rq_pad - rq)),
-                        constant_values=-(2 ** 30))
-    abits_rows = jnp.repeat(anc_bits.astype(jnp.int32), g, axis=1)
-    abits_rows = jnp.pad(abits_rows, ((0, 0), (0, rq_pad - rq)))
-    kvpos_p = jnp.pad(kv_pos, ((0, 0), (0, l_pad - l)), constant_values=-1)
-    kvnode_p = jnp.pad(kv_node.astype(jnp.int32), ((0, 0), (0, l_pad - l)),
-                       constant_values=-1)
+    qpos_rows = _rows(q_pos, g, rq_pad, -(2 ** 30))             # (B, rq, 1)
+    abits_rows = _rows(anc_bits, g, rq_pad, 0)
+    kvpos_p = _cols(kv_pos, l_pad)                              # (B, 1, L)
+    kvnode_p = _cols(kv_node, l_pad)
 
     grid = (b, kvh, l_pad // block_kv)
     out = pl.pallas_call(
@@ -256,10 +261,10 @@ def tree_verify_attention_pallas(q, k, v, q_pos, kv_pos, kv_node, anc_bits, *,
                           num_meta=num_meta, scale=scale),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, rq_pad), lambda bi, hi, ki: (bi, 0)),
-            pl.BlockSpec((1, rq_pad), lambda bi, hi, ki: (bi, 0)),
-            pl.BlockSpec((1, block_kv), lambda bi, hi, ki: (bi, ki)),
-            pl.BlockSpec((1, block_kv), lambda bi, hi, ki: (bi, ki)),
+            pl.BlockSpec((1, rq_pad, 1), lambda bi, hi, ki: (bi, 0, 0)),
+            pl.BlockSpec((1, rq_pad, 1), lambda bi, hi, ki: (bi, 0, 0)),
+            pl.BlockSpec((1, 1, block_kv), lambda bi, hi, ki: (bi, 0, ki)),
+            pl.BlockSpec((1, 1, block_kv), lambda bi, hi, ki: (bi, 0, ki)),
             pl.BlockSpec((1, 1, rq_pad, hd_pad), lambda bi, hi, ki: (bi, hi, 0, 0)),
             pl.BlockSpec((1, 1, block_kv, hd_pad), lambda bi, hi, ki: (bi, hi, ki, 0)),
             pl.BlockSpec((1, 1, block_kv, hd_pad), lambda bi, hi, ki: (bi, hi, ki, 0)),

@@ -11,6 +11,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels.block_attention import (
     tree_verify_attention_pallas,
@@ -20,6 +21,7 @@ from repro.kernels.fused_heads import fused_heads_topk_pallas
 from repro.kernels.fused_verify import fused_verify_pallas
 from repro.kernels.paged_attention import paged_verify_attention_pallas
 from repro.kernels.rwkv6_scan import rwkv6_scan_pallas
+from repro.sharding.policy import active_mesh, batch_axes
 
 
 def on_tpu() -> bool:
@@ -85,10 +87,21 @@ def fused_verify(p1_logits, proposals, *, criterion: str, top_k: int = 1,
         from repro.kernels import ref            # nothing to scan — oracle
         return ref.fused_verify(p1_logits, proposals, criterion=criterion,
                                 top_k=top_k, epsilon=epsilon)
-    return fused_verify_pallas(p1_logits, proposals, criterion=criterion,
-                               top_k=top_k, epsilon=float(epsilon),
-                               block_rows=block_rows, block_v=block_v,
-                               interpret=interp)
+    run = functools.partial(fused_verify_pallas, criterion=criterion,
+                            top_k=top_k, epsilon=float(epsilon),
+                            block_rows=block_rows, block_v=block_v,
+                            interpret=interp)
+    mesh = active_mesh()
+    if mesh is not None:
+        # GSPMD cannot partition a Mosaic kernel (it would replicate it
+        # behind an implicit all-gather): run it per batch shard, with the
+        # model-sharded vocab gathered explicitly
+        ax = batch_axes(mesh, p1_logits.shape[0])
+        run = jax.shard_map(
+            run, mesh=mesh, in_specs=(P(ax, None, None), P(ax, None)),
+            out_specs=(P(ax, None), P(ax), P(ax, None), P(ax)),
+            check_vma=False)
+    return run(p1_logits, proposals)
 
 
 @functools.partial(jax.jit, static_argnames=("vocab", "top_t", "block_rows",

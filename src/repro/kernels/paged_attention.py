@@ -55,15 +55,13 @@ def _paged_attn_kernel(tbl_ref,                                # scalar prefetch
     q = q_ref[0, 0].astype(jnp.float32)            # (RQ = kq*G, hd)
     k = k_ref[0, 0].astype(jnp.float32)            # (page_size, hd)
     v = v_ref[0, 0].astype(jnp.float32)            # (page_size, hd)
-    qpos = qpos_ref[0]                             # (RQ,) int32 (row -> q pos)
-    kvpos = kvpos_ref[0]                           # (page_size,) int32
+    qp = qpos_ref[0]                               # (RQ, 1) int32 (row -> q pos)
+    kp = kvpos_ref[0, 0]                           # (1, page_size) int32
 
     scores = jax.lax.dot_general(
         q * scale, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)        # (RQ, page_size)
 
-    qp = qpos[:, None]
-    kp = kvpos[None, :]
     mask = (kp >= 0) & (kp <= qp)
     if window:
         mask &= (qp - kp < window) | (kp < num_meta)
@@ -118,15 +116,19 @@ def paged_verify_attention_pallas(q, kp, vp, tbl, q_pos, kv_pos, *,
 
     qpos_rows = jnp.repeat(q_pos, g, axis=1)                     # (B, rq)
     qpos_rows = jnp.pad(qpos_rows, ((0, 0), (0, rq_pad - rq)),
-                        constant_values=-(2 ** 30))
+                        constant_values=-(2 ** 30))[:, :, None]
+    # one (1, ps) row per (slot, page), so each block equals its array's
+    # last two dims whatever the page size
+    kvpos_pages = kv_pos.astype(jnp.int32).reshape(b, P, 1, ps)
 
     grid = (b, kvh, P)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,                     # tbl: SMEM, feeds index maps
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, rq_pad), lambda bi, hi, pi, tbl: (bi, 0)),
-            pl.BlockSpec((1, ps), lambda bi, hi, pi, tbl: (bi, pi)),
+            pl.BlockSpec((1, rq_pad, 1), lambda bi, hi, pi, tbl: (bi, 0, 0)),
+            pl.BlockSpec((1, 1, 1, ps),
+                         lambda bi, hi, pi, tbl: (bi, pi, 0, 0)),
             pl.BlockSpec((1, 1, rq_pad, hd_pad),
                          lambda bi, hi, pi, tbl: (bi, hi, 0, 0)),
             # the paged gather: DMA the physical page this slot maps here
@@ -149,7 +151,7 @@ def paged_verify_attention_pallas(q, kp, vp, tbl, q_pos, kv_pos, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, rq_pad, hd_pad), q.dtype),
         interpret=interpret,
-    )(tbl.astype(jnp.int32), qpos_rows, kv_pos.astype(jnp.int32), qr, kr, vr)
+    )(tbl.astype(jnp.int32), qpos_rows, kvpos_pages, qr, kr, vr)
 
     out = out[:, :, :rq, :hd].reshape(b, kvh, kq, g, hd)
     return out.transpose(0, 2, 1, 3, 4).reshape(b, kq, h, hd)

@@ -13,101 +13,42 @@ Usage:
       --shape train_4k --mesh single
   PYTHONPATH=src python -m repro.launch.dryrun --all --mesh both
 """
-# The VERY FIRST lines, before ANY other import (jax locks the device count
-# on first backend init):
+import argparse
+import json
 import os
-# 512 placeholder devices for the production mesh; expensive LLVM codegen
-# passes disabled (pure CPU-backend compile-time saving — verified to leave
-# cost_analysis flops/bytes and the HLO collectives unchanged).
-os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
-                           "--xla_llvm_disable_expensive_passes=true")
+import re
+import time
+import traceback
+from typing import Dict, Optional
 
-import argparse  # noqa: E402
-import json  # noqa: E402
-import re  # noqa: E402
-import time  # noqa: E402
-import traceback  # noqa: E402
-from typing import Dict, Optional  # noqa: E402
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-import numpy as np  # noqa: E402
-
-from repro.config import (  # noqa: E402
+from repro.config import (
     INPUT_SHAPES,
     DecodeConfig,
     ModelConfig,
     TrainConfig,
     get_config,
 )
-from repro.launch import steps as steps_lib  # noqa: E402
-from repro.launch.mesh import (  # noqa: E402
+from repro.launch import steps as steps_lib
+from repro.launch.hlo import collective_bytes
+from repro.launch.mesh import (
     HBM_BW,
     ICI_BW,
     PEAK_FLOPS_BF16,
     make_production_mesh,
 )
-from repro.models import model as model_lib  # noqa: E402
-from repro.optim import optimizer_init  # noqa: E402
-from repro.sharding import (  # noqa: E402
+from repro.models import model as model_lib
+from repro.optim import optimizer_init
+from repro.sharding import (
     batch_specs,
     named,
     param_specs,
     state_specs,
 )
-from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
-
-COLLECTIVE_OPS = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
-                  "collective-permute")
-
-_DTYPE_BYTES = {
-    "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3": 1, "f8e5m2": 1,
-    "s64": 8, "s32": 4, "s16": 2, "s8": 1, "u64": 8, "u32": 4, "u16": 2,
-    "u8": 1, "pred": 1, "c64": 8, "c128": 16,
-}
-
-_SHAPE_RE = re.compile(r"\b(f64|f32|f16|bf16|s64|s32|s16|s8|u64|u32|u16|u8|pred)\[([0-9,]*)\]")
-
-
-def _shape_bytes(m: re.Match) -> int:
-    dt, dims = m.group(1), m.group(2)
-    n = 1
-    if dims:
-        for d in dims.split(","):
-            n *= int(d)
-    return n * _DTYPE_BYTES[dt]
-
-
-def collective_bytes(hlo_text: str) -> Dict[str, int]:
-    """Sum output-shape bytes of every collective op in the compiled HLO.
-
-    Handles scalar results (``%x = bf16[8,128] all-gather(...)``), tuple
-    results (``%x = (f32[16,16], f32[16,16]) all-to-all(...)``) and async
-    ``-start`` forms (whose ``-done`` twin carries no new traffic)."""
-    out: Dict[str, int] = {op: 0 for op in COLLECTIVE_OPS}
-    count: Dict[str, int] = {op: 0 for op in COLLECTIVE_OPS}
-    op_re = re.compile(
-        r"=\s+.*?\b(" + "|".join(COLLECTIVE_OPS) + r")(-start)?\(")
-    for line in hlo_text.splitlines():
-        stripped = line.strip()
-        m = op_re.search(stripped)
-        if not m:
-            continue
-        known = m.group(1)
-        total = 0
-        for dt, dims in _SHAPE_RE.findall(stripped[: m.start(1)]):
-            n = 1
-            if dims:
-                for d in dims.split(","):
-                    n *= int(d)
-            total += n * _DTYPE_BYTES[dt]
-        out[known] += total
-        count[known] += 1
-    out_nonzero = {k: v for k, v in out.items() if v}
-    return {"bytes_by_op": out_nonzero,
-            "counts": {k: v for k, v in count.items() if v},
-            "total_bytes": sum(out.values())}
-
 
 def active_params(cfg: ModelConfig, n_total: int) -> int:
     """Active parameter count for MODEL_FLOPS (MoE: routed experts scaled by
@@ -226,7 +167,7 @@ def run_combo(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
     n_chips = int(np.prod(mesh.devices.shape))
     spec = INPUT_SHAPES[shape_name]
     t0 = time.time()
-    with mesh:
+    with jax.set_mesh(mesh):
         jitted, args = build_lowering(cfg, shape_name, mesh,
                                       serve_bf16=serve_bf16, remat=remat)
         lowered = jitted.lower(*args)
@@ -341,7 +282,7 @@ def run_handoff(arch: str, out_dir: str, *, verbose: bool = True) -> Dict:
     params = model_lib.init(jax.random.PRNGKey(0), cfg)
 
     def lower_pair(donate: bool):
-        with mesh:
+        with jax.set_mesh(mesh):
             sess = DecodeSession(params, cfg, dec, mesh=mesh, donate=donate)
             fns = sess.serving_fns(ecfg)
             state = jax.eval_shape(fns.init, jnp.zeros((), jnp.int32))
@@ -425,6 +366,13 @@ def _write(out_dir: str, tag: str, rec: Dict) -> None:
 
 
 def main() -> None:
+    # 512 placeholder devices for the production mesh; expensive LLVM codegen
+    # passes disabled (pure CPU-backend compile-time saving — verified to
+    # leave cost_analysis flops/bytes and the HLO collectives unchanged).
+    # Set here, before the first device query initializes the backend, and
+    # never on import.
+    os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
+                               "--xla_llvm_disable_expensive_passes=true")
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None, choices=list(INPUT_SHAPES))

@@ -3,6 +3,11 @@
 ``make_production_mesh`` is a function (not a module-level constant) so that
 importing this module never touches jax device state — the dry-run must set
 XLA_FLAGS before anything initializes the backend.
+
+Every mesh is built with ``Auto`` axes: the model and serving code is
+written for GSPMD propagation (``with_sharding_constraint`` hints under
+``jax.set_mesh``), while ``jax.make_mesh`` defaults to ``Explicit`` axes,
+under which those hints are rejected.
 """
 from __future__ import annotations
 
@@ -11,15 +16,20 @@ import jax
 from repro.config import MeshConfig
 
 
+def _auto_mesh(shape, axes):
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 = 256 chips per pod; 2×16×16 = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_mesh(mc: MeshConfig):
-    return jax.make_mesh(mc.shape, mc.axes)
+    return _auto_mesh(mc.shape, mc.axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1, *, pod: int = 1,
@@ -45,8 +55,8 @@ def make_host_mesh(data: int = 1, model: int = 1, *, pod: int = 1,
             f"{n} — set XLA_FLAGS=--xla_force_host_platform_device_count="
             f"{need} before jax initializes")
     if pod > 1:
-        return jax.make_mesh((p, d, m), ("pod", "data", "model"))
-    return jax.make_mesh((d, m), ("data", "model"))
+        return _auto_mesh((p, d, m), ("pod", "data", "model"))
+    return _auto_mesh((d, m), ("data", "model"))
 
 
 # Hardware constants for roofline analysis (TPU v5e, per chip)

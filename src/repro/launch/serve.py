@@ -1,7 +1,7 @@
 """Serving launcher: restore a checkpoint and decode request batches with
 blockwise parallel decoding.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch granite-3-8b \
+    PYTHONPATH=src python -m repro.launch.serve --arch granite-3-8b --smoke \
         --ckpt-dir /tmp/ckpt --batch 4 --max-new 32 \
         [--criterion topk --top-k 2] [--policy topk_tree] [--sched sjf] \
         [--policy draft_model --draft-arch granite-3-8b \
@@ -34,7 +34,10 @@ losslessly.  Both modes (static batch and ``--engine``) thread the bundle
 through the same ``DecodeSession``.
 
 Runs the prefill + serve_step loop (the same entry points the multi-pod
-dry-run lowers) on the host devices with the reduced config.
+dry-run lowers).  By default the arch is served at its published widths
+and dtypes, with parameters drawn in one jitted program (straight into
+their shardings under a mesh); ``--smoke`` serves the reduced smoke config
+in float32 instead, the size for CPU runs and CI.
 
 ``--engine`` switches to the continuous-batching engine (repro.serving):
 2×batch mixed-length requests are scheduled through ``--batch`` slots with
@@ -59,12 +62,17 @@ import numpy as np
 from repro.checkpoint import latest_step, restore
 from repro.config import DecodeConfig, get_config
 from repro.data.synthetic import MarkovLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
+    ap.add_argument("--smoke", action="store_true",
+                    help="serve the arch's reduced smoke config in float32 "
+                         "(CPU runs, CI) instead of its published widths "
+                         "and dtypes")
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=16)
@@ -108,8 +116,7 @@ def main():
     ap.add_argument("--fused-verify", action="store_true",
                     help="route block acceptance through the one-pass "
                          "Pallas accept kernel (kernels/fused_verify; "
-                         "token-identical opt-in — interpret-mode, i.e. "
-                         "slow, off TPU)")
+                         "token-identical opt-in)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--engine", action="store_true",
                     help="serve through the continuous-batching engine "
@@ -159,10 +166,20 @@ def main():
                          "KV-handoff queue (0 = auto)")
     args = ap.parse_args()
 
-    cfg = get_config(args.arch, smoke=True).replace(dtype="float32")
+    enable_compile_cache()
+    cfg = get_config(args.arch, smoke=args.smoke)
+    if args.smoke:
+        cfg = cfg.replace(dtype="float32")
     if cfg.is_encoder_only:
         raise SystemExit(f"{args.arch} is encoder-only — no decode path")
-    params = M.init(jax.random.PRNGKey(args.seed), cfg)
+
+    mesh = None
+    if args.mesh_data > 0:
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh(args.mesh_data, args.mesh_model,
+                              pod=args.mesh_pod, require=True)
+        print(f"[serve] mesh {dict(mesh.shape)} over {mesh.size} devices")
+    params = M.init_params(jax.random.PRNGKey(args.seed), cfg, mesh)
     if args.ckpt_dir and latest_step(args.ckpt_dir) is not None:
         params, extra = restore(args.ckpt_dir, params)
         print(f"[serve] restored step {latest_step(args.ckpt_dir)} "
@@ -189,19 +206,12 @@ def main():
         batch["patch_embeds"] = jnp.zeros((args.batch, 4, cfg.d_model),
                                           jnp.float32)
 
-    mesh = None
-    if args.mesh_data > 0:
-        from repro.launch.mesh import make_host_mesh
-        mesh = make_host_mesh(args.mesh_data, args.mesh_model,
-                              pod=args.mesh_pod, require=True)
-        print(f"[serve] mesh {dict(mesh.shape)} over {mesh.size} devices")
-
     groups = parse_policy_groups(args.policies)
     if groups and not (args.engine or args.http):
         raise SystemExit("--policies configures per-request slot groups in "
                          "the continuous-batching engine: add --engine "
                          "(or --http)")
-    bundles = draft_bundle(cfg, args, groups)
+    bundles = draft_bundle(cfg, args, groups, mesh)
 
     if args.http:
         serve_http(params, cfg, dec, args, mesh=mesh, bundles=bundles,
@@ -269,17 +279,20 @@ def parse_policy_groups(spec: str):
     return groups
 
 
-def draft_bundle(cfg, args, groups=None):
+def draft_bundle(cfg, args, groups=None, mesh=None):
     """Build the auxiliary draft ``ModelBundle`` when any served policy is
-    draft_model (None otherwise): the --draft-arch smoke config (default:
-    the primary arch), restored from --draft-ckpt when given."""
+    draft_model (None otherwise): the --draft-arch config (default: the
+    primary arch; smoke-sized under --smoke), restored from --draft-ckpt
+    when given."""
     if args.policy != "draft_model" and "draft_model" not in (groups or {}):
         return None
     from repro.core.bundle import ModelBundle
 
     dcfg = get_config(args.draft_arch or args.arch,
-                      smoke=True).replace(dtype="float32", bpd_enabled=False)
-    dparams = M.init(jax.random.PRNGKey(args.seed + 7), dcfg)
+                      smoke=args.smoke).replace(bpd_enabled=False)
+    if args.smoke:
+        dcfg = dcfg.replace(dtype="float32")
+    dparams = M.init_params(jax.random.PRNGKey(args.seed + 7), dcfg, mesh)
     if args.draft_ckpt and latest_step(args.draft_ckpt) is not None:
         dparams, extra = restore(args.draft_ckpt, dparams)
         print(f"[serve] draft model: restored step "
@@ -388,10 +401,11 @@ def serve_http(params, cfg, dec, args, *, mesh=None, bundles=None,
     asyncio.run(run())
 
 
-async def _http_demo(srv):
-    """One end-to-end streamed request against the live server, over a
-    real socket: asserts SSE token + done events and green health checks,
-    exiting non-zero on any miss — the CI server-smoke contract."""
+async def stream_one(srv, prompt, max_new: int):
+    """Stream one request end to end against a live server, over a real
+    socket: asserts green health checks and SSE token + done events whose
+    tokens agree, raising SystemExit on any miss.  Returns ``(raw SSE
+    text, done payload)``."""
     import asyncio
     import json
 
@@ -407,10 +421,10 @@ async def _http_demo(srv):
         status = (await get(path)).splitlines()[0]
         print(f"[serve] {path} -> {status}")
         if "200" not in status:
-            raise SystemExit(f"--http-demo: {path} returned {status!r}")
+            raise SystemExit(f"stream_one: {path} returned {status!r}")
 
-    body = json.dumps({"prompt": [5, 6, 7, 8], "max_new": 12,
-                       "stream": True}).encode()
+    body = json.dumps({"prompt": [int(t) for t in prompt],
+                       "max_new": int(max_new), "stream": True}).encode()
     r, w = await asyncio.open_connection(srv.host, srv.port)
     w.write(b"POST /v1/generate HTTP/1.1\r\n"
             + f"Host: {srv.host}\r\n".encode()
@@ -418,8 +432,6 @@ async def _http_demo(srv):
     await w.drain()
     raw = (await r.read()).decode()
     w.close()
-    print("[serve] SSE stream:")
-    print("    " + "\n    ".join(ln for ln in raw.splitlines() if ln))
     events, cur = [], None
     for ln in raw.splitlines():
         if ln.startswith("event: "):
@@ -430,12 +442,20 @@ async def _http_demo(srv):
               for t in d["tokens"]]
     dones = [d for kind, d in events if kind == "done"]
     if not tokens or not dones:
-        raise SystemExit("--http-demo: stream missing token/done SSE events")
+        raise SystemExit("stream_one: stream missing token/done SSE events")
     done = dones[0]
     # the done payload repeats the full stream — they must agree exactly
     if tokens != done["tokens"]:
-        raise SystemExit("--http-demo: streamed tokens disagree with the "
+        raise SystemExit("stream_one: streamed tokens disagree with the "
                          "done payload")
+    return raw, done
+
+
+async def _http_demo(srv):
+    """The CI server-smoke contract: one streamed self-request, printed."""
+    raw, done = await stream_one(srv, [5, 6, 7, 8], 12)
+    print("[serve] SSE stream:")
+    print("    " + "\n    ".join(ln for ln in raw.splitlines() if ln))
     print(f"[serve] demo ok: {done['generated']} tokens streamed, "
           f"k̂={done['mean_accepted']:.2f}")
 

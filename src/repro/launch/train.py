@@ -21,6 +21,7 @@ from repro.checkpoint import latest_step, restore, save
 from repro.config import TrainConfig, get_config
 from repro.data.synthetic import MarkovLM, MaskedFrames
 from repro.launch import steps as steps_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.models import seq2seq as S
 from repro.optim import optimizer_init
@@ -63,6 +64,7 @@ def main():
     ap.add_argument("--log-every", type=int, default=20)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke).replace(dtype="float32")
     tc = TrainConfig(global_batch=args.batch, seq_len=args.seq, lr=args.lr,
                      steps=args.steps, warmup_steps=max(args.steps // 10, 10),

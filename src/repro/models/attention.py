@@ -272,15 +272,13 @@ def cache_write(cache: Dict, cfg: ModelConfig, layer_idx: int, k, v, positions) 
                 cache = cache_write(cache, cfg, layer_idx, k[:, :nres],
                                     v[:, :nres], positions[:nres])
             k, v, positions = k[:, -keep:], v[:, -keep:], positions[-keep:]
-        slots = _slot_for(positions, buf_len, nres)
-        new = dict(cache)
-        new["k"] = cache["k"].at[:, slots].set(k.astype(cache["k"].dtype))
-        new["v"] = cache["v"].at[:, slots].set(v.astype(cache["v"].dtype))
-        new["pos"] = cache["pos"].at[:, slots].set(
-            jnp.broadcast_to(positions.astype(jnp.int32), (b, positions.shape[0])))
-        return new
+        # one write path for prefill and decode: the TPU compiler aborts
+        # (scatter_emitter) on the shared-index k/v/pos scatter of a long
+        # prefill, and takes the per-row form
+        positions = jnp.broadcast_to(positions[None, :],
+                                     (b, positions.shape[0]))
 
-    # per-row decode write: positions (B, S)
+    # per-row write: positions (B, S)
     slots = _slot_for(positions, buf_len, nres)                    # (B, S)
 
     def row_write(buf, slot, val):

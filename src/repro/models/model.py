@@ -30,6 +30,7 @@ from repro.models.layers import (
     norm_init,
     unembed_apply,
 )
+from repro.sharding.policy import param_shardings
 
 
 # ---------------------------------------------------------------------------
@@ -62,6 +63,21 @@ def init(key, cfg: ModelConfig) -> Dict:
         p["mask_embed"] = jax.random.normal(
             jax.random.fold_in(key, 99), (cfg.d_model,), dtype) * 0.02
     return p
+
+
+def init_params(key, cfg: ModelConfig, mesh=None) -> Dict:
+    """``init`` as one jitted program: parameters are drawn on the device
+    in ``cfg.param_dtype`` and, given ``mesh``, straight into
+    ``sharding.policy.param_shardings`` — never assembled whole on one
+    device first."""
+    def init_params_program(k):
+        return init(k, cfg)
+
+    if mesh is None:
+        return jax.jit(init_params_program)(key)
+    shardings = param_shardings(jax.eval_shape(init_params_program, key),
+                                mesh)
+    return jax.jit(init_params_program, out_shardings=shardings)(key)
 
 
 # ---------------------------------------------------------------------------

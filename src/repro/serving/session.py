@@ -216,7 +216,7 @@ class DecodeSession:
         mesh = self.mesh
 
         def call(*args):
-            with mesh:
+            with jax.set_mesh(mesh):
                 return fn(*args)
 
         call._cache_size = getattr(fn, "_cache_size", None)
@@ -658,20 +658,25 @@ class DecodeSession:
                 caches=model_lib.reset_cache_rows(state.caches, mask),
                 policy_state=policy_state)
 
+        # the slot state is donated on accelerators, so a step or an
+        # admission never holds two copies of the KV slab
+        state_dn = (2,) if self.donate else ()  # state follows (params, aux)
+        first_dn = (0,) if self.donate else ()
         if mesh is None:
             return ServingFns(init=jax.jit(init_slots),
-                              admit=jax.jit(admit),
-                              step=jax.jit(step_windowed),
-                              evict=jax.jit(evict),
+                              admit=jax.jit(admit, donate_argnums=state_dn),
+                              step=jax.jit(step_windowed,
+                                           donate_argnums=state_dn),
+                              evict=jax.jit(evict, donate_argnums=first_dn),
                               prefill=jax.jit(prefill),
-                              attach=jax.jit(attach),
-                              attach_many=jax.jit(attach_many),
+                              attach=jax.jit(attach, donate_argnums=first_dn),
+                              attach_many=jax.jit(attach_many,
+                                                  donate_argnums=first_dn),
                               paged=paged_geom)
 
         rep = NamedSharding(mesh, P())
         mask_sh = NamedSharding(mesh, P(sharding_policy.batch_axes(mesh, s)))
         aux_sh = self.aux_shardings
-        state_dn = (2,) if self.donate else ()  # state follows (params, aux)
         admit_in = (self.param_shardings, aux_sh, slot_sh, rep,
                     rep, rep, rep, rep)
         if paged_geom is not None:
@@ -711,8 +716,7 @@ class DecodeSession:
                 donate_argnums=state_dn)),
             evict=self._with_mesh(jax.jit(
                 evict, in_shardings=(slot_sh, mask_sh),
-                out_shardings=slot_sh,
-                donate_argnums=(0,) if self.donate else ())),
+                out_shardings=slot_sh, donate_argnums=first_dn)),
             prefill=self._with_mesh(jax.jit(
                 prefill,
                 in_shardings=(self.param_shardings, aux_sh, prompts_sh,
@@ -720,10 +724,9 @@ class DecodeSession:
                 out_shardings=pkt_sh)),
             attach=self._with_mesh(jax.jit(
                 attach, in_shardings=attach_in, out_shardings=slot_sh,
-                donate_argnums=(0,) if self.donate else ())),
+                donate_argnums=first_dn)),
             attach_many=self._with_mesh(jax.jit(
                 attach_many, in_shardings=attach_many_in,
-                out_shardings=slot_sh,
-                donate_argnums=(0,) if self.donate else ())),
+                out_shardings=slot_sh, donate_argnums=first_dn)),
             paged=paged_geom,
         )

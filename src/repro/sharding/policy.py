@@ -346,20 +346,16 @@ def data_axis_size(mesh: Mesh) -> int:
                      if a in mesh.axis_names)
 
 
-def _active_mesh():
-    try:
-        from jax.interpreters import pxla
-
-        m = pxla.thread_resources.env.physical_mesh
-        return None if m.empty else m
-    except Exception:
-        return None
+def active_mesh():
+    """The mesh set by ``jax.set_mesh`` (readable while tracing), or None."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def maybe_shard(x, spec: P):
     """with_sharding_constraint that no-ops when no mesh is active, so model
     code can carry GSPMD hints without making tests mesh-dependent."""
-    if _active_mesh() is None:
+    if active_mesh() is None:
         return x
     return jax.lax.with_sharding_constraint(x, spec)
 
@@ -369,7 +365,7 @@ def maybe_shard_expert(x):
     the data axes, experts over model.  Axes are derived from the ACTIVE
     mesh (so the same model code lowers on single-pod and multi-pod meshes)
     and dropped when the dim doesn't divide (e.g. batch=1 long-context)."""
-    mesh = _active_mesh()
+    mesh = active_mesh()
     if mesh is None:
         return x
     b_ax = batch_axes(mesh, x.shape[0])

@@ -1,12 +1,23 @@
-"""Shared tiny-model fixtures.  Tests run on 1 CPU device (the 512-device
-XLA_FLAGS override is set only inside repro.launch.dryrun)."""
+"""Shared tiny-model fixtures.
+
+Tests run on 8 host CPU devices, so the mesh tests (the CPU rehearsal of
+the multi-chip paths) always run.  The device-count flag is appended to any
+``XLA_FLAGS`` the caller set, before JAX starts a backend; a count the
+caller chose is kept."""
 from __future__ import annotations
 
-import jax
-import numpy as np
-import pytest
+import os
 
-from repro.config import DecodeConfig, ModelConfig
+_FLAGS = os.environ.get("XLA_FLAGS", "")
+if "--xla_force_host_platform_device_count" not in _FLAGS:
+    os.environ["XLA_FLAGS"] = (
+        _FLAGS + " --xla_force_host_platform_device_count=8").strip()
+
+import jax  # noqa: E402
+import numpy as np  # noqa: E402
+import pytest  # noqa: E402
+
+from repro.config import DecodeConfig, ModelConfig  # noqa: E402
 
 
 def pytest_configure(config):
@@ -16,9 +27,8 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers", "serving: continuous-batching serving engine tests")
     config.addinivalue_line(
-        "markers", "sharded: host-mesh sharded decode tests (need "
-        "XLA_FLAGS=--xla_force_host_platform_device_count=8; skip on "
-        "1-device hosts)")
+        "markers", "sharded: host-mesh sharded decode tests (on the 8 "
+        "host devices this conftest sets up)")
 
 
 def tiny_dense(**kw) -> ModelConfig:

@@ -8,7 +8,7 @@ import pytest
 
 from repro.config import INPUT_SHAPES, DecodeConfig, get_config
 from repro.launch import steps as steps_lib
-from repro.launch.dryrun import collective_bytes
+from repro.launch.hlo import collective_bytes
 from repro.models.cache import attn_buf_len
 
 
@@ -131,3 +131,30 @@ def test_ring_buffer_wraparound_generation():
     gt, _ = D.greedy_decode(params, cfg, dec, batch)
     np.testing.assert_array_equal(np.asarray(bt[:, :48]),
                                   np.asarray(gt[:, :48]))
+
+
+@pytest.mark.parametrize("env_dir", [None, "/cache/from/env"])
+def test_enable_compile_cache_dir(monkeypatch, env_dir):
+    """The env var, when set, stands (no other dir is configured);
+    otherwise the cache goes to the fixed ``<checkout>/.jax_cache``."""
+    import os
+
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    try:
+        got = compile_cache.enable_compile_cache()
+        if env_dir is None:
+            want = os.path.join(compile_cache.CHECKOUT, ".jax_cache")
+            assert got == want == jax.config.jax_compilation_cache_dir
+            assert os.path.isfile(os.path.join(compile_cache.CHECKOUT,
+                                               "pyproject.toml"))
+        else:
+            assert got == env_dir
+            assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

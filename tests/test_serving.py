@@ -265,3 +265,24 @@ def test_bpd_decode_per_row_budgets():
     _, stats = D.bpd_decode(params, cfg, dec, batch, max_new_rows=budgets)
     np.testing.assert_array_equal(np.asarray(stats["generated"]),
                                   np.asarray(budgets))
+
+
+@pytest.mark.parametrize("donate", [True, False])
+def test_single_device_serving_fns_donate_slot_state(donate):
+    """Without a mesh the serving functions still donate the slot state
+    when the session donates (the default on accelerators), so a step or
+    an admission never holds two copies of the KV slab on the chip."""
+    from repro.serving import DecodeSession
+
+    cfg = tiny_dense()
+    params = M.init(jax.random.PRNGKey(0), cfg)
+    sess = DecodeSession(params, cfg, DecodeConfig(max_new_tokens=8,
+                                                   block_k=4), donate=donate)
+    fns = sess.serving_fns(EngineConfig(num_slots=2, max_prompt_len=8,
+                                        max_new_cap=8))
+    state = fns.init(jnp.zeros((), jnp.int32))
+    n_state = len(jax.tree_util.tree_leaves(state))
+    step = fns.step.lower(params, {}, state).as_text()
+    evict = fns.evict.lower(state, jnp.zeros((2,), bool)).as_text()
+    assert step.count("tf.aliasing_output") == (n_state if donate else 0)
+    assert evict.count("tf.aliasing_output") == (n_state if donate else 0)

@@ -86,6 +86,22 @@ def test_params_sharded_on_model_axis(session):
         assert v.sharding.mesh.shape == session.mesh.shape
 
 
+def test_init_params_draws_into_param_shardings(mesh, dense):
+    """Jitted init lands each leaf in its ``param_shardings`` entry (no
+    whole copy on one device first) with the eager init's values."""
+    from repro.sharding import param_shardings
+
+    cfg, params, _, _ = dense
+    placed = M.init_params(jax.random.PRNGKey(0), cfg, mesh)
+    want = param_shardings(params, mesh)
+    for got, ref, sh in zip(jax.tree_util.tree_leaves(placed),
+                            jax.tree_util.tree_leaves(params),
+                            jax.tree_util.tree_leaves(want)):
+        assert got.sharding == sh
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=1e-6, atol=1e-7)
+
+
 def test_bpd_decode_token_identical(session, dense):
     cfg, params, dec, batch = dense
     ref_toks, ref_stats = D.bpd_decode(params, cfg, dec, batch)
