@@ -67,6 +67,7 @@ from repro.serving import (
     Request,
     Scheduler,
     aggregate_stats,
+    tracing,
 )
 from repro.serving.types import percentile
 
@@ -141,10 +142,8 @@ def run_engine(params, cfg, dec, ecfg, reqs, *, policies=None, reps=1):
         admits0, steps0 = eng.num_admits, eng.num_steps  # this rep only
         pre0, ov0 = eng.num_prefill_batches, eng.num_overlap_harvests
         bp0 = eng.num_attach_backpressure
-        # phase timers restart with the measured window (warm-up compiled)
-        eng.time_in_prefill = 0.0
-        eng.time_in_decode_dispatch = 0.0
-        eng.time_in_harvest = 0.0
+        # host time per phase: the engine's span totals over this rep
+        spans0 = tracing.totals()
         for r in reqs:                  # copies: reps stay isolated
             sched.submit(dataclasses.replace(r))
 
@@ -183,9 +182,10 @@ def run_engine(params, cfg, dec, ecfg, reqs, *, policies=None, reps=1):
         stats["model_calls"] = prefills + (eng.num_steps - steps0)
         # per-phase host-time attribution: where the serving loop's wall
         # time actually went (the ledger behind the speedup gates)
-        stats["time_in_prefill"] = eng.time_in_prefill
-        stats["time_in_decode_dispatch"] = eng.time_in_decode_dispatch
-        stats["time_in_harvest"] = eng.time_in_harvest
+        stats["span_seconds"] = {
+            name: t["seconds_total"]
+            - spans0.get(name, {}).get("seconds_total", 0.0)
+            for name, t in sorted(tracing.totals().items())}
         stats["overlap_harvests"] = eng.num_overlap_harvests - ov0
         if eng.disaggregated:
             stats["prefill_batches"] = eng.num_prefill_batches - pre0
@@ -486,11 +486,8 @@ def main():
     # unified engine pays one prefill FORWARD per admission; disaggregation
     # batches those into prefill-worker forwards
     for mode in ("engine", "disagg"):
-        st = res[mode]
-        print(f"serve/{mode}/time_in_prefill,{st['time_in_prefill']:.4f},s")
-        print(f"serve/{mode}/time_in_decode_dispatch,"
-              f"{st['time_in_decode_dispatch']:.4f},s")
-        print(f"serve/{mode}/time_in_harvest,{st['time_in_harvest']:.4f},s")
+        for name, sec in res[mode]["span_seconds"].items():
+            print(f"serve/{mode}/{name},{sec:.4f},s")
     print(f"serve/disagg/prefill_batches,{res['disagg']['prefill_batches']},"
           f"requests={res['config']['requests']}")
     print(f"serve/disagg/overlap_harvests,"
@@ -670,14 +667,8 @@ def main():
         "disagg_prefill_batches": res["disagg"]["prefill_batches"],
         "disagg_overlap_harvests": res["disagg"]["overlap_harvests"],
         "disagg_compile_counts": dcc,
-        "engine_time_in_prefill": res["engine"]["time_in_prefill"],
-        "engine_time_in_decode_dispatch":
-            res["engine"]["time_in_decode_dispatch"],
-        "engine_time_in_harvest": res["engine"]["time_in_harvest"],
-        "disagg_time_in_prefill": res["disagg"]["time_in_prefill"],
-        "disagg_time_in_decode_dispatch":
-            res["disagg"]["time_in_decode_dispatch"],
-        "disagg_time_in_harvest": res["disagg"]["time_in_harvest"],
+        "engine_span_seconds": res["engine"]["span_seconds"],
+        "disagg_span_seconds": res["disagg"]["span_seconds"],
         "config": res["config"],
     }
     # merge-write: BENCH_serve.json is shared with slo_harness.py (the

@@ -65,7 +65,7 @@ from repro.launch.serve import stream_one  # noqa: E402
 from repro.models import model as M  # noqa: E402
 from repro.serving import (ContinuousBatchingEngine, DecodeSession,  # noqa: E402
                            EngineConfig, Frontend, HTTPServer, Request,
-                           Scheduler)
+                           Scheduler, tracing)
 
 ARCH = "granite-3-8b"
 ONE_CHIP_LAYERS = 16
@@ -378,30 +378,13 @@ def compare_sharded(cfg, mesh, tokens, seed):
 # ---------------------------------------------------------------------------
 
 
-class CompileLog:
-    """Backend compile seconds per program and persistent-cache hits, from
-    JAX's own monitoring events."""
-
-    def __init__(self):
-        self.seconds = []
-        self.cache_hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, duration, **kw):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds.append((kw.get("fun_name", "?"), duration))
-
-    def _event(self, event, **kw):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-    def report(self):
-        for name, sec in self.seconds:
-            log(f"compile {name}: {sec:.2f} s")
-        log(f"compile total {sum(s for _, s in self.seconds):.2f} s over "
-            f"{len(self.seconds)} programs, {self.cache_hits} read from the "
-            f"persistent cache")
+def log_compiles(before):
+    """Backend compiles since the ``tracing.snapshot()`` ``before``, from
+    the program's own compile counter."""
+    now = tracing.snapshot()
+    n = now["compiles_total"] - before["compiles_total"]
+    sec = now["compile_seconds_total"] - before["compile_seconds_total"]
+    log(f"compiled {n} programs in {sec:.2f} s")
 
 
 def log_memory(after, devices):
@@ -531,7 +514,7 @@ def main(argv=None):
               f"devices, JAX found {len(devices)}", file=sys.stderr)
         return 2
     log(f"compile cache: {enable_compile_cache()}")
-    compiles = CompileLog()
+    compiles = tracing.snapshot()
     t0 = time.monotonic()
     try:
         if args.chips == 4:
@@ -542,7 +525,7 @@ def main(argv=None):
     except Failed as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1
-    compiles.report()
+    log_compiles(compiles)
     log_memory("all phases", used)
     log(f"all phases passed in {time.monotonic() - t0:.1f} s")
     print(json.dumps({"ok": True, "device": {

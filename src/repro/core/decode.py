@@ -122,105 +122,118 @@ def bpd_iteration(params, cfg: ModelConfig, dec: DecodeConfig,
             "beyond the accepted root-to-leaf path")
 
     # ---- parallel scoring of the k proposals (verify ∧ next-predict) ------
-    h = backend.embed_tokens(params, state.proposals)
-    if topo is None:
-        hidden, staged = backend.decode_block(params, h, state.caches,
-                                              pos_len)
-    else:
-        hidden, staged = backend.decode_block(params, h, state.caches,
-                                              pos_len, tree=topo)
-    logits = backend.head_logits(params, hidden)            # (B, k, K, V)
-    logits = logits[:, :, :block_k, :]
-    p1_logits = logits[:, :, 0, :]
+    with jax.named_scope("bpd.trunk"):
+        h = backend.embed_tokens(params, state.proposals)
+        if topo is None:
+            hidden, staged = backend.decode_block(params, h, state.caches,
+                                                  pos_len)
+        else:
+            hidden, staged = backend.decode_block(params, h, state.caches,
+                                                  pos_len, tree=topo)
+    with jax.named_scope("bpd.heads"):
+        logits = backend.head_logits(params, hidden)        # (B, k, K, V)
+        logits = logits[:, :, :block_k, :]
+        p1_logits = logits[:, :, 0, :]
 
     # ---- verify ------------------------------------------------------------
-    if topo is None:
-        accepts = pol.acceptor.accepts(state.proposals, p1_logits)
-        commit_tokens = state.proposals
-        path_nodes = None
-    else:
-        # Tree verify: node n is checked by p_1 at its PARENT node (each
-        # node's logits are ancestor-chain-conditioned thanks to the tree
-        # mask).  Permuting the logits by parent turns the tree accept into
-        # the ordinary chain accept — including the fused-kernel path; the
-        # trailing permutation slot only feeds the always-true column 0.
-        perm = tuple(topo.parents[1:]) + (0,)
-        acc_nodes = pol.acceptor.accepts(state.proposals,
-                                         p1_logits[:, perm, :])   # (B, N)
-        reach = [acc_nodes[:, 0]]                  # root: always accepted
-        for n in range(1, block_k):
-            reach.append(acc_nodes[:, n] & reach[topo.parents[n]])
-        reach = jnp.stack(reach, axis=1)                          # (B, N)
-        depth = jnp.asarray(topo.depths)
-        path_len = jnp.max(jnp.where(reach, depth[None, :] + 1, 0), axis=1)
-        # deepest reached node; argmax tie-break = lowest node id
-        chosen = jnp.argmax(jnp.where(reach, depth[None, :], -1), axis=1)
-        path_nodes = jnp.asarray(topo.path_matrix)[chosen]        # (B, D+1)
-        if path_nodes.shape[1] < block_k:
-            path_nodes = jnp.pad(
-                path_nodes, ((0, 0), (0, block_k - path_nodes.shape[1])),
-                constant_values=-1)
-        commit_tokens = jnp.take_along_axis(
-            state.proposals, jnp.clip(path_nodes, 0, block_k - 1), axis=1)
-        accepts = (jnp.arange(block_k, dtype=jnp.int32)[None, :]
-                   < path_len[:, None])            # chain-shaped for schedule
-    remaining = jnp.maximum(max_new - state.generated, 1)
-    khat, sched_state = pol.schedule.block_size(
-        accepts, remaining, state.policy_state.schedule)    # (B,) in [1, k]
-    frozen = state.finished if active is None else (state.finished | ~active)
-    khat = jnp.where(frozen, 0, khat)
+    with jax.named_scope("bpd.verify"):
+        if topo is None:
+            accepts = pol.acceptor.accepts(state.proposals, p1_logits)
+            commit_tokens = state.proposals
+            path_nodes = None
+        else:
+            # Tree verify: node n is checked by p_1 at its PARENT node
+            # (each node's logits are ancestor-chain-conditioned thanks to
+            # the tree mask).  Permuting the logits by parent turns the tree
+            # accept into the ordinary chain accept — including the
+            # fused-kernel path; the trailing permutation slot only feeds
+            # the always-true column 0.
+            perm = tuple(topo.parents[1:]) + (0,)
+            acc_nodes = pol.acceptor.accepts(
+                state.proposals, p1_logits[:, perm, :])           # (B, N)
+            reach = [acc_nodes[:, 0]]              # root: always accepted
+            for n in range(1, block_k):
+                reach.append(acc_nodes[:, n] & reach[topo.parents[n]])
+            reach = jnp.stack(reach, axis=1)                      # (B, N)
+            depth = jnp.asarray(topo.depths)
+            path_len = jnp.max(jnp.where(reach, depth[None, :] + 1, 0),
+                               axis=1)
+            # deepest reached node; argmax tie-break = lowest node id
+            chosen = jnp.argmax(jnp.where(reach, depth[None, :], -1), axis=1)
+            path_nodes = jnp.asarray(topo.path_matrix)[chosen]    # (B, D+1)
+            if path_nodes.shape[1] < block_k:
+                path_nodes = jnp.pad(
+                    path_nodes, ((0, 0), (0, block_k - path_nodes.shape[1])),
+                    constant_values=-1)
+            commit_tokens = jnp.take_along_axis(
+                state.proposals, jnp.clip(path_nodes, 0, block_k - 1), axis=1)
+            accepts = (jnp.arange(block_k, dtype=jnp.int32)[None, :]
+                       < path_len[:, None])        # chain-shaped for schedule
+        remaining = jnp.maximum(max_new - state.generated, 1)
+        khat, sched_state = pol.schedule.block_size(
+            accepts, remaining, state.policy_state.schedule)  # (B,) in [1, k]
+        frozen = (state.finished if active is None
+                  else (state.finished | ~active))
+        khat = jnp.where(frozen, 0, khat)
 
-    # ---- EOS handling -------------------------------------------------------
-    if dec.eos_id >= 0:
-        pos_in_block = jnp.arange(block_k, dtype=jnp.int32)[None, :]
-        iseos = (commit_tokens == dec.eos_id) & (pos_in_block < khat[:, None])
-        has_eos = jnp.any(iseos, axis=1)
-        first_eos = jnp.argmax(iseos, axis=1)
-        khat = jnp.where(has_eos, first_eos + 1, khat)
-    else:
-        has_eos = jnp.zeros((b,), bool)
+        # ---- EOS handling ---------------------------------------------------
+        if dec.eos_id >= 0:
+            pos_in_block = jnp.arange(block_k, dtype=jnp.int32)[None, :]
+            iseos = ((commit_tokens == dec.eos_id)
+                     & (pos_in_block < khat[:, None]))
+            has_eos = jnp.any(iseos, axis=1)
+            first_eos = jnp.argmax(iseos, axis=1)
+            khat = jnp.where(has_eos, first_eos + 1, khat)
+        else:
+            has_eos = jnp.zeros((b,), bool)
 
     # ---- accept -------------------------------------------------------------
-    widx = state.text_len[:, None] + jnp.arange(block_k, dtype=jnp.int32)[None, :]
-    wmask = jnp.arange(block_k, dtype=jnp.int32)[None, :] < khat[:, None]
+    with jax.named_scope("bpd.commit"):
+        offs = jnp.arange(block_k, dtype=jnp.int32)[None, :]
+        widx = state.text_len[:, None] + offs
+        wmask = offs < khat[:, None]
 
-    def row_write(buf, idx, vals, m):
-        old = buf[idx]
-        return buf.at[idx].set(jnp.where(m, vals, old))
+        def row_write(buf, idx, vals, m):
+            old = buf[idx]
+            return buf.at[idx].set(jnp.where(m, vals, old))
 
-    tokens = jax.vmap(row_write)(state.tokens, widx, commit_tokens, wmask)
-    caches = backend.commit(staged, khat)
-    if topo is not None:
-        # move the accepted path's KV into chain slots so later iterations
-        # see an ordinary committed chain
-        caches = model_lib.commit_tree_path(cfg, caches, path_nodes, khat,
-                                            pos_len, block_k)
-    generated = state.generated + khat
-    finished = state.finished | has_eos | (generated >= max_new)
+        tokens = jax.vmap(row_write)(state.tokens, widx, commit_tokens, wmask)
+        caches = backend.commit(staged, khat)
+        if topo is not None:
+            # move the accepted path's KV into chain slots so later
+            # iterations see an ordinary committed chain
+            caches = model_lib.commit_tree_path(cfg, caches, path_nodes,
+                                                khat, pos_len, block_k)
+        generated = state.generated + khat
+        finished = state.finished | has_eos | (generated >= max_new)
 
     # ---- next-block proposals (drafted from this same invocation) ----------
-    # the committed token at the new text_len - 1 (the last accepted slot;
-    # model-backed drafters re-feed it to keep their own cache in sync)
-    prev_token = jnp.take_along_axis(
-        commit_tokens, jnp.maximum(khat - 1, 0)[:, None], axis=1)[:, 0]
-    if topo is None:
-        slot = jnp.maximum(khat - 1, 0)
-    else:
-        # the accepted slot is the path's node at depth k̂-1 (root for k̂=0)
-        slot = jnp.take_along_axis(
-            path_nodes, jnp.maximum(khat - 1, 0)[:, None], axis=1)[:, 0]
-        slot = jnp.maximum(slot, 0)
-    draft_in = DraftInputs(
-        logits=logits, khat=khat, slot=slot,
-        text_len=state.text_len + khat, old_proposals=commit_tokens,
-        prev_token=prev_token, aux=aux_params or {})
-    proposals, draft_state = pol.drafter.draft(
-        draft_in, state.policy_state.drafter)
-    proposals = jnp.where(frozen[:, None], state.proposals, proposals)
-    policy_state = PolicyState(
-        drafter=_freeze_rows(frozen, state.policy_state.drafter, draft_state),
-        schedule=_freeze_rows(frozen, state.policy_state.schedule,
-                              sched_state))
+    with jax.named_scope("bpd.draft"):
+        # the committed token at the new text_len - 1 (the last accepted
+        # slot; model-backed drafters re-feed it to keep their own cache in
+        # sync)
+        prev_token = jnp.take_along_axis(
+            commit_tokens, jnp.maximum(khat - 1, 0)[:, None], axis=1)[:, 0]
+        if topo is None:
+            slot = jnp.maximum(khat - 1, 0)
+        else:
+            # the accepted slot is the path's node at depth k̂-1 (root for
+            # k̂=0)
+            slot = jnp.take_along_axis(
+                path_nodes, jnp.maximum(khat - 1, 0)[:, None], axis=1)[:, 0]
+            slot = jnp.maximum(slot, 0)
+        draft_in = DraftInputs(
+            logits=logits, khat=khat, slot=slot,
+            text_len=state.text_len + khat, old_proposals=commit_tokens,
+            prev_token=prev_token, aux=aux_params or {})
+        proposals, draft_state = pol.drafter.draft(
+            draft_in, state.policy_state.drafter)
+        proposals = jnp.where(frozen[:, None], state.proposals, proposals)
+        policy_state = PolicyState(
+            drafter=_freeze_rows(frozen, state.policy_state.drafter,
+                                 draft_state),
+            schedule=_freeze_rows(frozen, state.policy_state.schedule,
+                                  sched_state))
 
     return BPDState(
         tokens=tokens,

@@ -10,6 +10,8 @@ Layering:
                  workload driver, stats
   frontend.py  — asyncio facade: per-request token streams + back-pressure
   server.py    — stdlib HTTP/1.1 + SSE surface over the frontend
+  tracing.py   — ``serve.*`` spans (profiler annotations plus counters
+                 exported on /metrics) and the compile counter
 """
 from repro.serving.engine import (ContinuousBatchingEngine, PagePoolExhausted,
                                   PolicyGroup)

@@ -64,6 +64,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.config import DecodeConfig, ModelConfig
+from repro.serving import tracing
 from repro.serving.pages import PageAllocator, PagePoolExhausted
 from repro.serving.session import DecodeSession, ServingFns
 from repro.serving.types import (EngineConfig, FinishedRequest,
@@ -245,11 +246,11 @@ class ContinuousBatchingEngine:
         self.num_stream_syncs = 0  # poll_progress readbacks (streaming only)
         self.num_prefill_batches = 0   # prefill-worker forwards dispatched
         self.num_attach_backpressure = 0  # attach stalls (page pool full)
-        # per-phase host wall-clock attribution (the speedup ledger):
-        # where the serving loop actually spends its host time
-        self.time_in_prefill = 0.0          # prefill dispatch (admit incl.)
-        self.time_in_decode_dispatch = 0.0  # group-step dispatch, no sync
-        self.time_in_harvest = 0.0          # status pulls + retirement
+        # host time per phase is in the ``serve.engine.*`` spans
+        # (serving/tracing.py); ``_gap_from`` is when the last status pull
+        # returned, while a device program is still to be dispatched after
+        # it: the start of the host gap ``serve.engine.host_gap`` counts
+        self._gap_from: Optional[float] = None
         # harvest of one group completed while ANOTHER stepped group's
         # status was still unpulled (its device step still in flight) —
         # the async per-group stream overlap, asserted in tests
@@ -333,28 +334,33 @@ class ContinuousBatchingEngine:
     def admit(self, req: Request, *, now: Optional[float] = None) -> int:
         """Admit a request into a free slot of its policy's group; returns
         the global slot index."""
-        g = self.group_for(req.policy)
-        free = g.free_local()
-        if not free:
-            raise RuntimeError(
-                f"no free slot in policy group {g.name!r} — poll "
-                f"step()/harvest first")
-        slot = free[0]
-        prompt, p, src, max_new = self._padded(req)
-        extra = ()
-        if g.pages is not None:
-            # host-side page plan first: raises PagePoolExhausted (back-
-            # pressure, the scheduler requeues) before any device work, and
-            # reuses pooled pages for identical prompt prefixes (CoW)
-            tbl_row, write_mask = g.pages.plan_admit(
-                slot, req.prompt, p, max_new, self.block_k)
-            extra = (jnp.asarray(tbl_row), jnp.asarray(write_mask))
-        t0 = time.monotonic()
-        g.state = g.fns.admit(
-            self.params, self.aux_params, g.state, jnp.asarray(slot, I32),
-            jnp.asarray(prompt), jnp.asarray(p, I32),
-            jnp.asarray(max_new, I32), jnp.asarray(src), *extra)
-        self.time_in_prefill += time.monotonic() - t0
+        with tracing.span("serve.engine.admit", rid=req.rid):
+            return self._admit(req, now)
+
+    def _admit(self, req: Request, now: Optional[float]) -> int:
+        with tracing.span("serve.engine.admit_prep", rid=req.rid):
+            g = self.group_for(req.policy)
+            free = g.free_local()
+            if not free:
+                raise RuntimeError(
+                    f"no free slot in policy group {g.name!r} — poll "
+                    f"step()/harvest first")
+            slot = free[0]
+            prompt, p, src, max_new = self._padded(req)
+            extra = ()
+            if g.pages is not None:
+                # host-side page plan first: raises PagePoolExhausted
+                # (back-pressure, the scheduler requeues) before any device
+                # work, and reuses pooled pages for identical prompt
+                # prefixes (CoW)
+                tbl_row, write_mask = g.pages.plan_admit(
+                    slot, req.prompt, p, max_new, self.block_k)
+                extra = (jnp.asarray(tbl_row), jnp.asarray(write_mask))
+            args = (jnp.asarray(slot, I32), jnp.asarray(prompt),
+                    jnp.asarray(p, I32), jnp.asarray(max_new, I32),
+                    jnp.asarray(src)) + extra
+        g.state = g.fns.admit(self.params, self.aux_params, g.state, *args)
+        self._dispatched()
         g.status[slot] = 1          # known host-side: no readback needed
         self.num_admits += 1
         admit_time = time.monotonic() if now is None else now
@@ -409,7 +415,10 @@ class ContinuousBatchingEngine:
         shape, so the worker compiles once) and parks its rows in the
         handoff queue as ``HandoffRecord``s sharing the device packet.
         Dispatch-only — no device→host sync.  Returns rows parked."""
-        t0 = time.monotonic()
+        with tracing.span("serve.engine.prefill"):
+            return self._run_prefills(now)
+
+    def _run_prefills(self, now: Optional[float]) -> int:
         parked = 0
         w = self.prefill_width
         for g in self.groups:
@@ -439,6 +448,7 @@ class ContinuousBatchingEngine:
                 packet = g.fns.prefill(self.params, self.aux_params,
                                        jnp.asarray(prompts),
                                        jnp.asarray(plens), jnp.asarray(srcs))
+                self._dispatched()
                 self.num_prefill_batches += 1
                 t = time.monotonic() if now is None else now
                 for req, r, p, max_new in rows:
@@ -446,7 +456,6 @@ class ContinuousBatchingEngine:
                         req=req, packet=packet, row=r, prompt_len=p,
                         max_new=max_new, prefill_time=t))
                     parked += 1
-        self.time_in_prefill += time.monotonic() - t0
         return parked
 
     def attach_ready(self, *, now: Optional[float] = None) -> int:
@@ -460,6 +469,10 @@ class ContinuousBatchingEngine:
         ``attach_many`` dispatch (a per-record attach call would hand
         back the dispatch overhead that batching the prefill amortized).
         Returns the number of requests attached."""
+        with tracing.span("serve.engine.attach"):
+            return self._attach_ready(now)
+
+    def _attach_ready(self, now: Optional[float]) -> int:
         attached = 0
         w = self.prefill_width
         for g in self.groups:
@@ -509,6 +522,7 @@ class ContinuousBatchingEngine:
                 g.state = g.fns.attach_many(
                     g.state, pkt, jnp.asarray(rows), jnp.asarray(slots),
                     jnp.asarray(maxn), jnp.asarray(valid), *pextra)
+                self._dispatched()
                 t = time.monotonic() if now is None else now
                 for rec, slot, _ in batch:
                     g.status[slot] = 1  # known host-side: no readback needed
@@ -536,29 +550,32 @@ class ContinuousBatchingEngine:
         step still costs exactly one fused device→host sync, now off the
         critical path of the other groups' device work.
         """
-        t0 = time.monotonic()
         n = len(self.groups)
         order = [self.groups[(self._rr + i) % n] for i in range(n)]
         self._rr = (self._rr + 1) % n
         stepped = []
-        for g in order:
-            if not np.any(g.status & 1):
-                continue                     # idle group: no device work
-            g.state, status, iters = g.fns.step(self.params,
-                                                self.aux_params, g.state)
-            stepped.append((g, status, iters))
-        self.time_in_decode_dispatch += time.monotonic() - t0
+        with tracing.span("serve.engine.dispatch"):
+            for g in order:
+                if not np.any(g.status & 1):
+                    continue                 # idle group: no device work
+                g.state, status, iters = g.fns.step(self.params,
+                                                    self.aux_params, g.state)
+                stepped.append((g, status, iters))
+        if stepped:
+            self._dispatched()
         # the ONE per-group-step device->host round-trip: a fused (S,) int8
         # array carrying both the active and the finished bits (the harvest
         # decision) — pulled only after every group's step is in flight,
         # and each group's harvest runs before the NEXT group's pull
         out: List[FinishedRequest] = []
-        t1 = time.monotonic()
+        synced = None
         for idx, (g, status, iters) in enumerate(stepped):
             # one fused pull: the (S,) status plus the window's iteration
             # count (a windowed step is 1..steps_per_sync forwards — the
             # invocation accounting must count every one of them)
-            status_h, it = jax.device_get((status, iters))
+            with tracing.span("serve.engine.sync"):
+                status_h, it = jax.device_get((status, iters))
+            synced = time.perf_counter()
             g.status = np.array(status_h)    # writable host copy
             self.num_steps += int(it)
             self.num_host_syncs += 1
@@ -568,8 +585,18 @@ class ContinuousBatchingEngine:
                 # statuses were still unpulled (their device steps free to
                 # proceed) — the measurable async-stream overlap
                 self.num_overlap_harvests += 1
-        self.time_in_harvest += time.monotonic() - t1
+        # the device idles from here until the host dispatches its next
+        # program; with no active slot left, waiting is the traffic's
+        self._gap_from = synced if self.has_active() else None
         return out
+
+    def _dispatched(self) -> None:
+        """An admission or a step was just dispatched: close the host gap
+        that the last status pull opened."""
+        if self._gap_from is not None:
+            tracing.record("serve.engine.host_gap",
+                           time.perf_counter() - self._gap_from)
+            self._gap_from = None
 
     def harvest(self, *, now: Optional[float] = None) -> List[FinishedRequest]:
         """Retire finished slots of every group: copy outputs out, free
@@ -590,8 +617,12 @@ class ContinuousBatchingEngine:
         finishing group, counted in ``num_host_syncs``).
         """
         done_mask = (g.status & 2).astype(bool)
-        if not done_mask.any():
-            return []
+        n = int(done_mask.sum())
+        with tracing.span("serve.engine.harvest", n=n):
+            return self._retire(g, done_mask, now) if n else []
+
+    def _retire(self, g: PolicyGroup, done_mask: np.ndarray,
+                now: Optional[float]) -> List[FinishedRequest]:
         t = time.monotonic() if now is None else now
         out: List[FinishedRequest] = []
         # one FUSED transfer for all four arrays — a single host round-trip
@@ -635,21 +666,24 @@ class ContinuousBatchingEngine:
         reach the front end through ``FinishedRequest.tokens`` instead.
         """
         out: List[Tuple[Request, np.ndarray]] = []
-        for g in self.groups:
-            live = [i for i in range(g.num_slots)
-                    if (g.status[i] & 1) and g.slot_meta[i] is not None]
-            if not live:
-                continue
-            tokens, text_len = jax.device_get(
-                (g.state.tokens, g.state.text_len))
-            self.num_stream_syncs += 1
-            for i in live:
-                meta = g.slot_meta[i]
-                start = meta["prompt_len"] + meta["emitted"]
-                end = int(text_len[i])
-                if end > start:
-                    out.append((meta["req"], tokens[i, start:end].copy()))
-                    meta["emitted"] = end - meta["prompt_len"]
+        with tracing.span("serve.engine.poll"):
+            for g in self.groups:
+                live = [i for i in range(g.num_slots)
+                        if (g.status[i] & 1) and g.slot_meta[i] is not None]
+                if not live:
+                    continue
+                with tracing.span("serve.engine.poll_sync"):
+                    tokens, text_len = jax.device_get(
+                        (g.state.tokens, g.state.text_len))
+                self.num_stream_syncs += 1
+                for i in live:
+                    meta = g.slot_meta[i]
+                    start = meta["prompt_len"] + meta["emitted"]
+                    end = int(text_len[i])
+                    if end > start:
+                        out.append((meta["req"],
+                                    tokens[i, start:end].copy()))
+                        meta["emitted"] = end - meta["prompt_len"]
         return out
 
     def pull_group(self, g: PolicyGroup) -> Tuple[np.ndarray, np.ndarray,

@@ -40,6 +40,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro.serving import tracing
 from repro.serving.scheduler import Scheduler
 from repro.serving.types import Request
 
@@ -94,6 +95,10 @@ class Frontend:
         self._running = False
         self._ready = False
         self._draining = False
+        # serve-loop hand-offs between the event loop and the executor
+        # thread, timed into ``serve.frontend.hop``
+        self._hop_from: Optional[float] = None
+        self._tick_end = 0.0
         # service counters (on top of scheduler/engine ones) for /metrics
         self.requests_total = 0
         self.rejected_total = 0
@@ -210,6 +215,17 @@ class Frontend:
     def _tick(self) -> List[Tuple[int, StreamEvent]]:
         """One scheduler step, run on the executor thread — the ONLY place
         the engine/scheduler state is touched after start()."""
+        t = time.perf_counter()
+        if self._hop_from is not None:
+            tracing.record("serve.frontend.hop", t - self._hop_from)
+            self._hop_from = None
+        try:
+            with tracing.span("serve.frontend.tick"):
+                return self._tick_body()
+        finally:
+            self._tick_end = time.perf_counter()
+
+    def _tick_body(self) -> List[Tuple[int, StreamEvent]]:
         with self._lock:
             drained, self._pending = self._pending, []
         for req in drained:
@@ -241,31 +257,43 @@ class Frontend:
     async def _serve_loop(self) -> None:
         loop = asyncio.get_running_loop()
         while self._running:
+            self._hop_from = time.perf_counter()
             events = await loop.run_in_executor(None, self._tick)
-            for rid, ev in events:
-                if ev.kind == "tokens":
-                    self.tokens_streamed += len(ev.data)
-                q = self._streams.get(rid)
-                if q is not None:
-                    q.put_nowait(ev)
-                    if ev.kind == "done":
-                        self.finished_total += 1
-                        del self._streams[rid]
-            if not events:
-                if self._draining and not self._streams:
-                    with self._lock:
-                        idle = not self._pending
-                    # quiescence read between ticks (executor calls are
-                    # strictly sequential, so this never races the engine)
-                    if idle and await loop.run_in_executor(
-                            None, self.scheduler.drained):
-                        return      # drain complete: the loop retires itself
-                await asyncio.sleep(self.idle_sleep_s)
+            # the way back: the tick's end to this coroutine resuming
+            tracing.record("serve.frontend.hop",
+                           time.perf_counter() - self._tick_end)
+            with tracing.span("serve.frontend.loop"):
+                for rid, ev in events:
+                    if ev.kind == "tokens":
+                        self.tokens_streamed += len(ev.data)
+                    q = self._streams.get(rid)
+                    if q is not None:
+                        q.put_nowait(ev)
+                        if ev.kind == "done":
+                            self.finished_total += 1
+                            del self._streams[rid]
+                if not events:
+                    if self._draining and not self._streams:
+                        with self._lock:
+                            idle = not self._pending
+                        # quiescence read between ticks (executor calls are
+                        # strictly sequential, so this never races the
+                        # engine)
+                        if idle and await loop.run_in_executor(
+                                None, self.scheduler.drained):
+                            return  # drain complete: the loop retires
+                    await asyncio.sleep(self.idle_sleep_s)
 
     # -- observability -------------------------------------------------------
 
+    def active_slots(self) -> int:
+        eng = self.engine
+        return eng.ecfg.num_slots - len(eng.free_slots())
+
     def metrics(self) -> Dict[str, float]:
-        """Flat counter/gauge snapshot for the /metrics endpoint."""
+        """Flat counter/gauge snapshot for the /metrics endpoint, with every
+        span and counter of ``serving.tracing``.  Takes a tracing snapshot,
+        so each span's ``seconds_max`` restarts."""
         sch, eng = self.scheduler, self.engine
         return {
             "requests_total": self.requests_total,
@@ -275,7 +303,7 @@ class Frontend:
             "preemptions_total": sch.preemptions,
             "backpressure_requeues_total": sch.backpressure_events,
             "queue_depth": self.queue_depth(),
-            "active_slots": (eng.ecfg.num_slots - len(eng.free_slots())),
+            "active_slots": self.active_slots(),
             "num_slots": eng.ecfg.num_slots,
             "engine_steps_total": eng.num_steps,
             "engine_admits_total": eng.num_admits,
@@ -289,7 +317,5 @@ class Frontend:
             "handoff_backlog": eng.handoff_backlog(),
             "attach_backpressure_total": eng.num_attach_backpressure,
             "overlap_harvests_total": eng.num_overlap_harvests,
-            "time_in_prefill_seconds": eng.time_in_prefill,
-            "time_in_decode_dispatch_seconds": eng.time_in_decode_dispatch,
-            "time_in_harvest_seconds": eng.time_in_harvest,
+            **tracing.snapshot(),
         }

@@ -55,6 +55,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from repro.serving import tracing
 from repro.serving.engine import (ContinuousBatchingEngine, PagePoolExhausted,
                                   PolicyGroup)
 from repro.serving.types import (FinishedRequest, PreemptedRequest, Request,
@@ -247,6 +248,10 @@ class Scheduler:
         """Admit eligible requests into each group's free slots (preempting
         where a deadline demands it), then one engine step (= one BPD
         iteration per active group)."""
+        with tracing.span("serve.scheduler.step"):
+            return self._step(now)
+
+    def _step(self, now: Optional[float]) -> List[FinishedRequest]:
         t = time.monotonic() if now is None else now
         # preemption runs BEFORE admission/staging: a deadline-at-risk
         # request must claim its slot while it is still in the queue — the

@@ -197,7 +197,7 @@ class HTTPServer:
             self.begin_drain()
             writer.write(_json_response(202, "Accepted", {
                 "draining": True,
-                "in_flight": int(self.frontend.metrics()["active_slots"]),
+                "in_flight": self.frontend.active_slots(),
                 "queued": self.frontend.queue_depth()}))
         elif method == "POST" and path == "/v1/generate":
             await self._generate(body, writer)

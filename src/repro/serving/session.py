@@ -473,6 +473,10 @@ class DecodeSession:
             params from ``aux``; a source-drafting policy stores the
             request's src rows).
             """
+            with jax.named_scope("bpd.prefill"):
+                return prefill_body(params, aux, prompts, plens, srcs)
+
+        def prefill_body(params, aux, prompts, plens, srcs) -> PrefillPacket:
             w = prompts.shape[0]
             row_caches = kv_backend.row_init(cfg, context_len, block_k,
                                              batch=w)
@@ -524,6 +528,12 @@ class DecodeSession:
             arrive with ``write_mask=False`` and are left untouched in the
             pool.
             """
+            with jax.named_scope("bpd.install"):
+                return attach_body(state, packet, row, slot, max_new,
+                                   tbl_row, write_mask)
+
+        def attach_body(state: SlotBatch, packet: PrefillPacket, row, slot,
+                        max_new, tbl_row, write_mask) -> SlotBatch:
             take = lambda x: jax.lax.dynamic_slice_in_dim(  # noqa: E731
                 x, row, 1, axis=0)
             row_caches = jax.tree_util.tree_map(take, packet.caches)

@@ -27,6 +27,7 @@ from repro.serving import (
     EngineConfig,
     Request,
     Scheduler,
+    tracing,
 )
 
 pytestmark = pytest.mark.serving
@@ -222,15 +223,16 @@ def test_attach_backpressure_page_pool(stack):
 
 
 def test_phase_timers_and_overlap(stack):
-    """The per-phase host timers attribute wall time (satellite of the
-    engine-vs-static regression), and with two active groups each step
-    harvests group A while group B's device step is still in flight —
+    """The engine's spans attribute host time per phase (admission, step
+    dispatch, status pull and retirement), and with two active groups each
+    step harvests group A while group B's device step is still in flight —
     ``num_overlap_harvests`` counts exactly stepped_groups - 1 per step."""
     cfg, params, dec = stack
     ecfg = dataclasses.replace(ECFG, handoff_cap=8)
     eng = ContinuousBatchingEngine(params, cfg, dec, ecfg,
                                    policies={"exact": 1, "topk": 1})
     sched = Scheduler(eng)
+    before = tracing.totals()
     rng = np.random.default_rng(5)
     for i in range(6):
         sched.submit(Request(
@@ -240,9 +242,13 @@ def test_phase_timers_and_overlap(stack):
             max_new=int(rng.integers(3, 11))))
     finished = sched.run()
     assert len(finished) == 6
-    assert eng.time_in_prefill > 0.0
-    assert eng.time_in_decode_dispatch > 0.0
-    assert eng.time_in_harvest > 0.0
+    spent = {name: t["seconds_total"]
+             - before.get(name, {}).get("seconds_total", 0.0)
+             for name, t in tracing.totals().items()}
+    assert spent["serve.engine.prefill"] > 0.0     # disaggregated admission
+    assert spent["serve.engine.attach"] > 0.0
+    assert spent["serve.engine.dispatch"] > 0.0
+    assert spent["serve.engine.sync"] + spent["serve.engine.harvest"] > 0.0
     # both groups were active together at least once -> overlapped harvest
     assert eng.num_overlap_harvests > 0
     # overlap never exceeds (groups - 1) per step taken
