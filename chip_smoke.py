@@ -215,13 +215,13 @@ def cached_verify(engine, params, requests):
     def verify_forward(params, drafts, caches, length):
         h = backend.embed_tokens(params, drafts)
         hidden, _ = backend.decode_block(params, h, caches, length)
-        return backend.head_logits(params, hidden)           # (W, k, K, V)
+        return backend.p1_logits(params, hidden)             # (W, k, V)
 
     logits = verify_forward(sess.params, packet.proposals, packet.caches,
                             plens + cfg.num_meta_tokens)
     check(bool(jnp.all(jnp.isfinite(logits))),
-          "non-finite head logits in the verify step")
-    return logits[:, :, 0, :].astype(jnp.float32), packet.proposals
+          "non-finite p1 logits in the verify step")
+    return logits.astype(jnp.float32), packet.proposals
 
 
 def compare_cached(fwd, params, cfg, requests, p1_cached, drafts, length):

@@ -3,7 +3,11 @@
 The combined scoring/proposal formulation (§4) is used throughout: one model
 invocation per iteration serves simultaneously as the verification of the
 current block and the prediction of the next block, so decoding an output of
-length m costs (m / mean-k̂) + 1 invocations instead of m.
+length m costs (m / mean-k̂) + 1 invocations instead of m.  Inside that one
+invocation the step verifies first, from p_1 alone at the k block
+positions, and only then runs the other heads, at the one position the
+next block is drafted from (the accepted slot k̂-1): the vocabulary
+projection covers B·(k + block_k - 1) rows, not B·k·K.
 
 The loop is a ``jax.lax.while_loop`` with fully static shapes; per-row
 accepted block sizes k̂ let every batch row advance at its own rate.
@@ -37,12 +41,18 @@ from repro.models.layers import embed_apply
 
 
 class Backend(NamedTuple):
-    """Model functions the BPD engine needs."""
+    """Model functions the BPD engine needs.
+
+    A verify step calls ``p1_logits`` at every block position, and
+    ``head_logits(params, hidden, 1, block_k)`` (heads p_2..p_block_k) at
+    the accepted slot only.
+    """
 
     embed_tokens: Callable          # (params, tokens (B,S)) -> (B,S,d)
     decode_block: Callable          # (params, h, caches, length) -> (hidden, staged_caches)
     commit: Callable                # (caches, khat) -> caches
-    head_logits: Callable           # (params, hidden) -> (..., k, V)
+    head_logits: Callable           # (params, hidden, start=0, stop=None) -> (..., n, V)
+    p1_logits: Callable             # (params, hidden) -> (..., V)
 
 
 def causal_lm_backend(cfg: ModelConfig, *, kv_chunk: int = 0) -> Backend:
@@ -51,7 +61,9 @@ def causal_lm_backend(cfg: ModelConfig, *, kv_chunk: int = 0) -> Backend:
         decode_block=lambda p, h, c, ln, tree=None: model_lib.decode_block_step(
             p, cfg, h, c, ln, kv_chunk=kv_chunk, tree=tree),
         commit=lambda c, kh: model_lib.commit_caches(cfg, c, kh),
-        head_logits=lambda p, h: model_lib.all_head_logits(p, cfg, h),
+        head_logits=lambda p, h, start=0, stop=None: model_lib.all_head_logits(
+            p, cfg, h, start, stop),
+        p1_logits=lambda p, h: model_lib.base_logits(p, cfg, h),
     )
 
 
@@ -61,7 +73,9 @@ def seq2seq_backend(cfg: ModelConfig, enc_kvs, enc_mask=None) -> Backend:
         decode_block=lambda p, h, c, ln, tree=None: seq2seq_lib.decode_block_step(
             p, cfg, h, c, ln, enc_kvs, enc_mask, tree=tree),
         commit=lambda c, kh: model_lib.commit_caches(cfg, c, kh),
-        head_logits=lambda p, h: seq2seq_lib.all_head_logits(p, cfg, h),
+        head_logits=lambda p, h, start=0, stop=None: seq2seq_lib.all_head_logits(
+            p, cfg, h, start, stop),
+        p1_logits=lambda p, h: seq2seq_lib.base_logits(p, cfg, h),
     )
 
 
@@ -96,7 +110,15 @@ def bpd_iteration(params, cfg: ModelConfig, dec: DecodeConfig,
                   prefix_offset: int, max_new, active=None,
                   policy: Optional[DecodePolicy] = None,
                   aux_params=None) -> BPDState:
-    """One combined predict/verify/accept step.
+    """One combined predict/verify/accept step, verify first.
+
+    The trunk runs the k proposals; p_1's logits at those k positions
+    verify the block and commit k̂ tokens; then heads p_2..p_block_k run
+    on the hidden state at the accepted slot alone (chain: k̂-1; tree: the
+    path's node at depth k̂-1), beside p_1's logits already at hand there,
+    and the drafter proposes the next block from those (B, block_k, V)
+    logits.  The heads' rows at the other k-1 positions are never
+    computed: nothing reads them.
 
     max_new : int or (B,) int32 — per-row generation budget (the serving
               engine gives every slot its own request budget).
@@ -131,9 +153,7 @@ def bpd_iteration(params, cfg: ModelConfig, dec: DecodeConfig,
             hidden, staged = backend.decode_block(params, h, state.caches,
                                                   pos_len, tree=topo)
     with jax.named_scope("bpd.heads"):
-        logits = backend.head_logits(params, hidden)        # (B, k, K, V)
-        logits = logits[:, :, :block_k, :]
-        p1_logits = logits[:, :, 0, :]
+        p1_logits = backend.p1_logits(params, hidden)       # (B, k, V)
 
     # ---- verify ------------------------------------------------------------
     with jax.named_scope("bpd.verify"):
@@ -207,13 +227,8 @@ def bpd_iteration(params, cfg: ModelConfig, dec: DecodeConfig,
         generated = state.generated + khat
         finished = state.finished | has_eos | (generated >= max_new)
 
-    # ---- next-block proposals (drafted from this same invocation) ----------
-    with jax.named_scope("bpd.draft"):
-        # the committed token at the new text_len - 1 (the last accepted
-        # slot; model-backed drafters re-feed it to keep their own cache in
-        # sync)
-        prev_token = jnp.take_along_axis(
-            commit_tokens, jnp.maximum(khat - 1, 0)[:, None], axis=1)[:, 0]
+    # ---- heads at the accepted slot (drafted from this same invocation) ----
+    with jax.named_scope("bpd.heads"):
         if topo is None:
             slot = jnp.maximum(khat - 1, 0)
         else:
@@ -222,6 +237,23 @@ def bpd_iteration(params, cfg: ModelConfig, dec: DecodeConfig,
             slot = jnp.take_along_axis(
                 path_nodes, jnp.maximum(khat - 1, 0)[:, None], axis=1)[:, 0]
             slot = jnp.maximum(slot, 0)
+        # p_1 there is already computed: a masked max picks it out reading
+        # the (B, k, V) logits once, where the TPU's gather copies them first
+        at_slot = jnp.arange(p1_logits.shape[1])[None, :] == slot[:, None]
+        logits = jnp.max(jnp.where(at_slot[:, :, None], p1_logits, -jnp.inf),
+                         axis=1, keepdims=True)                # (B, 1, V)
+        if block_k > 1:
+            h_slot = hidden[jnp.arange(b), slot]                   # (B, d)
+            logits = jnp.concatenate(
+                [logits, backend.head_logits(params, h_slot, 1, block_k)],
+                axis=1)                                   # (B, block_k, V)
+
+    with jax.named_scope("bpd.draft"):
+        # the committed token at the new text_len - 1 (the last accepted
+        # slot; model-backed drafters re-feed it to keep their own cache in
+        # sync)
+        prev_token = jnp.take_along_axis(
+            commit_tokens, jnp.maximum(khat - 1, 0)[:, None], axis=1)[:, 0]
         draft_in = DraftInputs(
             logits=logits, khat=khat, slot=slot,
             text_len=state.text_len + khat, old_proposals=commit_tokens,
@@ -252,10 +284,10 @@ def initial_draft(pol: DecodePolicy, head_logits: jnp.ndarray,
                   prev_token=None, aux_params=None):
     """Draft the FIRST block from a prefill's head logits.
 
-    ``head_logits`` is (B, K, V) at the last context position — presented to
-    the drafter as a single pseudo block slot (slot 0, k̂ = 1), so the same
-    ``draft`` method covers prefill and loop iterations.  For
-    ``HeadsDrafter`` this reduces exactly to the historical
+    ``head_logits`` is (B, K, V) at the last context position — the shape
+    a loop iteration hands the drafter from its accepted slot (here slot 0,
+    k̂ = 1), so one ``draft`` method covers prefill and loop iterations.
+    For ``HeadsDrafter`` this reduces exactly to the historical
     ``argmax(head_logits)``; source-drafting policies get to draft from
     their own state immediately instead of spending one iteration on weak
     head proposals.
@@ -268,7 +300,7 @@ def initial_draft(pol: DecodePolicy, head_logits: jnp.ndarray,
     if prev_token is None:
         prev_token = jnp.zeros((b,), jnp.int32)
     din = DraftInputs(
-        logits=head_logits[:, None, :block_k, :],
+        logits=head_logits[:, :block_k, :],
         khat=jnp.ones((b,), jnp.int32),
         slot=jnp.zeros((b,), jnp.int32),
         text_len=jnp.broadcast_to(jnp.asarray(text_len, jnp.int32), (b,)),

@@ -169,9 +169,7 @@ class DraftModelDrafter(policy_lib.Drafter):
             logits = be.head_logits(params, hidden)    # (B, 1, K', V)
             return jnp.argmax(logits[:, 0, 0, :], axis=-1).astype(I32), caches
 
-        head_argmax = jnp.argmax(inputs.logits, axis=-1)        # (B, k, K)
-        verified = policy_lib._gather_slot(head_argmax, inputs.slot)[:, 0]
-        verified = verified.astype(I32)
+        verified = jnp.argmax(inputs.logits[:, 0], axis=-1).astype(I32)
         prev = jnp.asarray(inputs.prev_token, I32)
         pos0 = jnp.maximum(inputs.text_len - 1, 0)
 

@@ -35,14 +35,22 @@ def heads_init(key, cfg: ModelConfig, *, dtype=jnp.float32) -> Dict:
     }
 
 
-def heads_apply(p, cfg: ModelConfig, hidden, *, identity_p1: bool = True
-                ) -> jnp.ndarray:
-    """hidden: (..., d) -> (..., k, d) per-head decoder outputs."""
+def heads_apply(p, cfg: ModelConfig, hidden, *, identity_p1: bool = True,
+                start: int = 0, stop=None) -> jnp.ndarray:
+    """hidden: (..., d) -> (..., n, d) decoder outputs of heads
+    ``start .. stop-1`` (static; all k by default).
+
+    The first layer runs every head: a slice of ``w1`` along its head axis
+    (its middle one) is a copy of those weights on the TPU, dearer than
+    the rows it leaves out.  The second layer reads only the chosen heads'
+    ``w2`` (a slice of its leading axis reads in place)."""
+    heads = slice(start, stop)
     h = jnp.einsum("...d,dkh->...kh", hidden, p["w1"].astype(hidden.dtype))
-    h = jax.nn.relu(h + p["b1"].astype(hidden.dtype))
-    out = jnp.einsum("...kh,khd->...kd", h, p["w2"].astype(hidden.dtype))
-    out = out + p["b2"].astype(hidden.dtype) + hidden[..., None, :]
-    if identity_p1:
+    h = jax.nn.relu(h + p["b1"].astype(hidden.dtype))[..., heads, :]
+    out = jnp.einsum("...kh,khd->...kd", h,
+                     p["w2"][heads].astype(hidden.dtype))
+    out = out + p["b2"][heads].astype(hidden.dtype) + hidden[..., None, :]
+    if identity_p1 and start == 0:
         out = out.at[..., 0, :].set(hidden)
     return out
 

@@ -73,10 +73,14 @@ class PolicyState(NamedTuple):
 class DraftInputs(NamedTuple):
     """Everything one verify forward exposes to a ``Drafter``.
 
-    ``logits`` is the full head tensor of the iteration that just verified
-    the current block — reusing it keeps drafting free (no extra model
-    calls), exactly like the paper's combined scoring/proposal
-    formulation (§4).
+    ``logits`` holds the heads' logits at the accepted slot of the
+    iteration that just verified the current block: row 0 is p_1 there
+    (whose argmax is the verified greedy token), row i head p_{i+1}.  The
+    step verifies from p_1 first and then runs the other heads at that one
+    position, so drafting stays free (no extra model calls), exactly like
+    the paper's combined scoring/proposal formulation (§4), and the heads
+    at the other block positions, which no drafter reads, are never
+    computed.  At prefill the position is the last context token.
 
     ``prev_token`` / ``aux`` are the bundle-aware model-call seam: a
     drafter backed by its own model (``core.draft.DraftModelDrafter``)
@@ -87,19 +91,13 @@ class DraftInputs(NamedTuple):
     only read the verify forward ignore both.
     """
 
-    logits: jnp.ndarray       # (B, k, K, V) all-head logits at every slot
+    logits: jnp.ndarray       # (B, block_k, V) head logits at the slot
     khat: jnp.ndarray         # (B,) accepted block size this iteration
-    slot: jnp.ndarray         # (B,) accepted slot index = max(k̂ - 1, 0)
+    slot: jnp.ndarray         # (B,) the slot: max(k̂ - 1, 0), a tree's node
     text_len: jnp.ndarray     # (B,) text length AFTER accepting this block
     old_proposals: jnp.ndarray  # (B, k) the block that was just verified
     prev_token: Any = ()      # (B,) committed token at text_len - 1
     aux: Any = ()             # {bundle name: params} for model-backed drafters
-
-
-def _gather_slot(x: jnp.ndarray, slot: jnp.ndarray) -> jnp.ndarray:
-    """x: (B, k, ...) gathered at per-row slot -> (B, ...)."""
-    idx = slot.reshape((-1,) + (1,) * (x.ndim - 1))
-    return jnp.take_along_axis(x, idx, axis=1)[:, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -318,8 +316,7 @@ class HeadsDrafter(Drafter):
     slot proposes block slot i (already computed by the verify forward)."""
 
     def draft(self, inputs: DraftInputs, state: Any):
-        head_argmax = jnp.argmax(inputs.logits, axis=-1)        # (B, k, K)
-        return _gather_slot(head_argmax, inputs.slot), state
+        return jnp.argmax(inputs.logits, axis=-1), state        # (B, K)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -350,8 +347,7 @@ class InputCopyDrafter(Drafter):
     def draft(self, inputs: DraftInputs, state):
         src = state["src"]
         b, k = inputs.old_proposals.shape
-        head_argmax = jnp.argmax(inputs.logits, axis=-1)
-        verified = _gather_slot(head_argmax, inputs.slot)[:, 0]  # p_1 argmax
+        verified = jnp.argmax(inputs.logits[:, 0], axis=-1)      # p_1 argmax
         # decoder position 0 is BOS, so output index = position - 1; block
         # slot i sits at position text_len + i
         out_idx = (inputs.text_len[:, None] - 1 + self.offset
@@ -390,9 +386,8 @@ class TopKTreeDrafter(Drafter):
     def draft(self, inputs: DraftInputs, state):
         b, k = inputs.old_proposals.shape
         topo = self.tree_topology(k)
-        head_logits = _gather_slot(inputs.logits, inputs.slot)   # (B,K,V)
         need = int(topo.ranks.max()) + 1
-        _, ids = jax.lax.top_k(head_logits, need)                # (B,K,need)
+        _, ids = jax.lax.top_k(inputs.logits, need)              # (B,K,need)
         d = jnp.asarray(topo.depths)                             # head index
         r = jnp.asarray(topo.ranks)                              # rank index
         # node 0 is (depth 0, rank 0) = head p_1's argmax = the verified token
@@ -491,15 +486,14 @@ class LocalityDrafter(Drafter):
         proposals = (a + c + 1) // 2
         if self.window:
             vocab = inputs.logits.shape[-1]
-            hl = _gather_slot(inputs.logits, inputs.slot)   # (B, heads, V)
+            hl = inputs.logits                              # (B, heads, V)
             hidx = jnp.minimum(jnp.arange(k), hl.shape[1] - 1)
             deltas = jnp.arange(-self.window, self.window + 1, dtype=I32)
             cands = jnp.clip(proposals[..., None] + deltas, 0, vocab - 1)
             scores = jnp.take_along_axis(hl[:, hidx, :], cands, axis=-1)
             pick = jnp.argmax(scores, axis=-1)
             proposals = jnp.take_along_axis(cands, pick[..., None], -1)[..., 0]
-        head_argmax = jnp.argmax(inputs.logits, axis=-1)
-        verified = _gather_slot(head_argmax, inputs.slot)[:, 0]  # p_1 argmax
+        verified = jnp.argmax(inputs.logits[:, 0], axis=-1)      # p_1 argmax
         proposals = proposals.at[:, 0].set(verified)
         return proposals.astype(I32), {"grid": buf}
 

@@ -253,19 +253,28 @@ def project_vocab(params, cfg: ModelConfig, h) -> jnp.ndarray:
     return logits
 
 
-def all_head_logits(params, cfg: ModelConfig, hidden) -> jnp.ndarray:
-    """hidden: (..., d) -> (..., k, V) logits of p_1..p_k (paper Fig. 3)."""
-    if not cfg.bpd_enabled or "bpd_heads" not in params:
-        # headless model: p_1 only (greedy-decodable via block_k=1)
-        return project_vocab(params, cfg, hidden)[..., None, :]
-    outs = heads_apply(params["bpd_heads"], cfg, hidden,
-                       identity_p1=cfg.bpd_identity_p1)
+def _has_heads(params, cfg: ModelConfig) -> bool:
+    return cfg.bpd_enabled and "bpd_heads" in params
+
+
+def all_head_logits(params, cfg: ModelConfig, hidden, start: int = 0,
+                    stop=None) -> jnp.ndarray:
+    """hidden: (..., d) -> (..., n, V) logits of heads p_{start+1} ..
+    p_stop (static; p_1..p_k by default, paper Fig. 3).  A headless model
+    has p_1 only (greedy-decodable via block_k=1), so ``start`` ≥ 1 gives
+    no rows there."""
+    if not _has_heads(params, cfg):
+        outs = hidden[..., None, :][..., start:stop, :]
+    else:
+        outs = heads_apply(params["bpd_heads"], cfg, hidden,
+                           identity_p1=cfg.bpd_identity_p1,
+                           start=start, stop=stop)
     return project_vocab(params, cfg, outs)
 
 
 def base_logits(params, cfg: ModelConfig, hidden) -> jnp.ndarray:
-    """p_1 logits only."""
-    if cfg.bpd_enabled and not cfg.bpd_identity_p1:
+    """hidden: (..., d) -> (..., V) p_1 logits only."""
+    if _has_heads(params, cfg) and not cfg.bpd_identity_p1:
         from repro.core.heads import head_apply_single
         hidden = head_apply_single(params["bpd_heads"], cfg, hidden, 0,
                                    identity_p1=False)

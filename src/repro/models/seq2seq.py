@@ -109,8 +109,8 @@ def project_vocab(params, cfg, h):
     return model_lib.project_vocab(params, cfg, h)
 
 
-def all_head_logits(params, cfg, hidden):
-    return model_lib.all_head_logits(params, cfg, hidden)
+def all_head_logits(params, cfg, hidden, start=0, stop=None):
+    return model_lib.all_head_logits(params, cfg, hidden, start, stop)
 
 
 def base_logits(params, cfg, hidden):

@@ -254,9 +254,9 @@ def test_input_copy_drafts_source_aligned():
     drafter = P.InputCopyDrafter()
     src = jnp.asarray([[10, 11, 12, 13, 14, 15]], I32)
     state = drafter.init_state(None, None, {"src": src}, 1)
-    b, k, K, V = 1, 4, 4, 20
-    logits = np.full((b, k, K, V), -10.0, np.float32)
-    logits[0, 1, 0, 7] = 10.0       # p_1 argmax at accepted slot 1 -> 7
+    b, K, V = 1, 4, 20
+    logits = np.full((b, K, V), -10.0, np.float32)
+    logits[0, 0, 7] = 10.0          # p_1 argmax at accepted slot 1 -> 7
     inputs = P.DraftInputs(
         logits=jnp.asarray(logits), khat=jnp.asarray([2], I32),
         slot=jnp.asarray([1], I32), text_len=jnp.asarray([3], I32),
