@@ -2,13 +2,15 @@
 
 After the window has closed and the program's state is freed, a sample of
 the finished requests, drawn from the seed and always holding the one with
-the most served tokens, is run once through ``bench.reference`` over its
-prompt and served tokens.  For each served token the gap by which the
-reference's logit of that token lies below the reference's best logit at
-that position is read; the widest gap is compared with its limit.  The
-served tokens are what the window streamed to its clients: the first comes
-from the padded admission prefill, the rest from k-wide verify steps
-through the KV cache under exact acceptance, all greedy.
+the most served tokens, is run once through the plain reference of the
+configuration's family (``bench/models/<model_type>.py``, built from
+``bench.reference``) over its prompt and served tokens.  For each served
+token the gap by which the reference's logit of that token lies below the
+reference's best logit at that position is read; the widest gap is
+compared with its limit.  The served tokens are what the window streamed
+to its clients: the first comes from the padded admission prefill, the
+rest from k-wide verify steps through the KV cache under exact
+acceptance, all greedy.
 
 The control reads the same positions with the reference in a lower
 precision put in the program's place: the gap of the token that the lower
@@ -16,7 +18,6 @@ precision puts first (``control_gap``).
 """
 from __future__ import annotations
 
-import functools
 from typing import Dict, List, Sequence
 
 import jax
@@ -47,23 +48,18 @@ def sample(records: Sequence[Dict], seed: int, *, min_tokens: int,
     return picked
 
 
-@functools.partial(jax.jit, static_argnames=("k",))
-def _gaps(final_scale, table, h, rows, served, *, k):
-    """Reference logits at ``rows`` of h; the gap of each served token."""
-    logits = reference.head_logits(final_scale, table, h[rows], k=k,
-                                   fp8=False)
-    best = jnp.max(logits, -1)
-    return best - jnp.take_along_axis(logits, served[:, None], -1)[:, 0], \
-        logits
+@jax.jit
+def _gaps(logits, served):
+    """The gap of each row's served token below the row's best logit."""
+    return jnp.max(logits, -1) - jnp.take_along_axis(
+        logits, served[:, None], -1)[:, 0]
 
 
-@functools.partial(jax.jit, static_argnames=("k",))
-def _control_gaps(final_scale, table, h_ctrl, rows, ref_logits, *, k):
-    ctrl = reference.head_logits(final_scale, table, h_ctrl[rows], k=k,
-                                 fp8=True)
-    first = jnp.argmax(ctrl, -1)
-    return jnp.max(ref_logits, -1) - jnp.take_along_axis(
-        ref_logits, first[:, None], -1)[:, 0]
+@jax.jit
+def _control_gaps(ref_logits, ctrl_logits):
+    """The gap, in the reference's logits, of the token that the control
+    puts first."""
+    return _gaps(ref_logits, jnp.argmax(ctrl_logits, -1))
 
 
 def _rows(n_prompt: int, n_served: int, count: int) -> np.ndarray:
@@ -74,20 +70,20 @@ def _rows(n_prompt: int, n_served: int, count: int) -> np.ndarray:
     return np.concatenate([rows, pad]).astype(np.int32)
 
 
-def served_gaps(params: Dict, c: Dict, picked: Sequence[Dict], geo: Dict, *,
-                control: bool = False) -> Dict:
+def served_gaps(model, params: Dict, c: Dict, picked: Sequence[Dict],
+                geo: Dict, *, control: bool = False) -> Dict:
     """Widest gap of the served tokens (and of the control's, if asked)
-    over the picked requests, with the number of tokens compared.  ``geo``
-    is the engine geometry of the cell's traffic: sequences are padded to
-    ``max_prompt_len + max_new_cap``, served rows to ``max_new_cap``."""
-    k = reference.consts(c)
+    over the picked requests, with the number of tokens compared, by the
+    reference forward of the configuration's family ``model``
+    (``bench/models/<model_type>.py``).  ``geo`` is the engine geometry of
+    the cell's traffic: sequences are padded to ``max_prompt_len +
+    max_new_cap``, served rows to ``max_new_cap``."""
     seqs = [r["prompt"] + r["tokens"] for r in picked]
     length = geo["max_prompt_len"] + geo["max_new_cap"]
     n_rows = reference.bucketed(geo["max_new_cap"])
-    hs = reference.hidden_states(params, c, seqs, length=length)
-    hc = (reference.hidden_states(params, c, seqs, length=length, fp8=True)
+    hs = model.hidden_states(params, c, seqs, length=length)
+    hc = (model.hidden_states(params, c, seqs, length=length, fp8=True)
           if control else [None] * len(seqs))
-    final, table = params["final_norm"]["scale"], params["embed"]["table"]
     widest = ctrl_widest = 0.0
     count = 0
     for r, h, h8 in zip(picked, hs, hc):
@@ -95,13 +91,13 @@ def served_gaps(params: Dict, c: Dict, picked: Sequence[Dict], geo: Dict, *,
         rows = _rows(len(r["prompt"]), n, n_rows)
         served = np.zeros((len(rows),), np.int32)
         served[:n] = r["tokens"]
-        gaps, logits = _gaps(final, table, h, jnp.asarray(rows),
-                             jnp.asarray(served), k=k)
+        logits = model.logits(params, c, h[rows])
+        gaps = _gaps(logits, jnp.asarray(served))
         widest = max(widest, float(jnp.max(gaps[:n])))
         count += n
         if control:
-            cg = _control_gaps(final, table, h8, jnp.asarray(rows), logits,
-                               k=k)
+            cg = _control_gaps(logits,
+                               model.logits(params, c, h8[rows], fp8=True))
             ctrl_widest = max(ctrl_widest, float(jnp.max(cg[:n])))
     out = {"served_logit_gap": widest, "tokens_compared": count,
            "requests_compared": len(picked)}

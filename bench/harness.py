@@ -6,11 +6,12 @@ with SSE on localhost, through ``HTTPServer`` → ``Frontend`` →
 with the clients in the same process and event loop.
 
   set-up    the configuration's weights drawn on the device
-            (``bench.weights``), the engine built, the cell's own shapes warmed by one request
-            through the server (one admission at ``max_prompt_len``, one
-            step, one evict), then a pre-roll of the same traffic that is
-            not counted; ``setup_s`` runs from process start to the
-            window's start;
+            (``bench.weights``, in the layout of the configuration's family,
+            ``bench/models/<model_type>.py``), the engine built, the cell's
+            own shapes warmed by one request through the server (one
+            admission at ``max_prompt_len``, one step, one evict), then a
+            pre-roll of the same traffic that is not counted; ``setup_s``
+            runs from process start to the window's start;
   window    ``--seconds`` of the traffic, timed on the client side; with
             ``--trace 1`` the profiler records its first ``TRACE_S``
             seconds and the per-layer metrics are read over that part;
@@ -101,51 +102,21 @@ def enable_compile_cache() -> str:
     return path
 
 
-def program_config(c: Dict):
-    """The program's ``ModelConfig`` for a configuration file: the
-    registry entry with the file's sizes.  Refuses a file that states
-    something the program cannot run (it has no granite multipliers)."""
-    from repro.config import get_config
-
-    hd = c.get("head_dim") or c["hidden_size"] // c["num_attention_heads"]
-    fixed = {"attention_multiplier": hd ** -0.5, "embedding_multiplier": 1.0,
-             "residual_multiplier": 1.0, "logits_scaling": 1.0,
-             "rms_norm_eps": 1e-6}
-    for key, want in fixed.items():
-        if not math.isclose(float(c[key]), want, rel_tol=1e-9):
-            raise spec.SpecError(
-                f"{key}={c[key]}: the served model computes {want} and has "
-                f"no option for another value")
-    cfg = get_config(c["registry"]).replace(
-        num_layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-        num_heads=c["num_attention_heads"],
-        num_kv_heads=c["num_key_value_heads"], head_dim=hd,
-        d_ff=c["intermediate_size"], vocab_size=c["vocab_size"],
-        rope_theta=float(c["rope_theta"]),
-        tie_embeddings=bool(c["tie_word_embeddings"]),
-        bpd_k=c["bpd_heads"], bpd_hidden=c["bpd_head_hidden"],
-        param_dtype=c["torch_dtype"], dtype=c["compute_dtype"])
-    if cfg.padded_vocab_size != c["padded_vocab_size"]:
-        raise spec.SpecError(f"program pads the vocabulary to "
-                             f"{cfg.padded_vocab_size}, the file says "
-                             f"{c['padded_vocab_size']}")
-    return cfg
-
-
-def check_layout(c: Dict, cfg) -> None:
-    """The weights the benchmark makes have the program's tree, shapes and
-    dtypes."""
+def check_layout(model, c: Dict, cfg) -> None:
+    """The weights the benchmark makes from the family ``model``'s layout
+    have the program's tree, shapes and dtypes."""
     import jax
     from repro.models import model as M
 
     want = jax.eval_shape(lambda: M.init(jax.random.PRNGKey(0), cfg))
-    have = param_structs(c)
+    have = param_structs(model, c)
     if (jax.tree_util.tree_structure(want)
             != jax.tree_util.tree_structure(have)
             or jax.tree_util.tree_leaves(want)
             != jax.tree_util.tree_leaves(have)):
-        raise spec.SpecError("the program's parameter layout differs from "
-                             "bench/weights.py's")
+        raise spec.SpecError(f"the program's parameter layout differs from "
+                             f"the layout of bench/models/"
+                             f"{c['model_type']}.py")
 
 
 def make_mesh(c: Dict):
@@ -282,14 +253,17 @@ def end_to_end(records: List[Dict], marks: Dict, setup_s: float) -> Dict:
 
 
 def layer_run(records, marks, probe: Probe, trace: Optional[Dict],
-              c: Dict, chips: int, peaks: Optional[Dict]) -> Dict:
-    """What the per-layer readers read: the traced part of the window."""
+              cell: spec.Cell, chips: int, peaks: Optional[Dict]) -> Dict:
+    """What the per-layer readers read: the traced part of the window, with
+    the configuration and its family (``model``), which counts the work a
+    step requires (``bench.flops``)."""
     t0 = marks["t0"]
     t1 = marks.get("t_trace", marks["t1"])
     c0 = marks["counters0"]
     c1 = marks.get("counters_trace", marks["counters1"])
     return {
-        "t0": t0, "t1": t1, "config": c, "chips": chips, "peaks": peaks,
+        "t0": t0, "t1": t1, "config": cell.config, "model": cell.model,
+        "chips": chips, "peaks": peaks,
         "num_slots": probe.num_slots, "trace": trace,
         "tokens_per_s": window_tokens(records, t0, t1) / (t1 - t0),
         "tokens_streamed": c1["tokens_streamed_total"]
@@ -364,9 +338,9 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     if cache:
         log(f"compile cache: {enable_compile_cache()}")
     compiles = CompileLog.get()
-    c, mix = cell.config, cell.traffic
-    cfg = program_config(c)
-    check_layout(c, cfg)
+    c, model, mix = cell.config, cell.model, cell.traffic
+    cfg = model.program_config(c)
+    check_layout(model, c, cfg)
     mesh = make_mesh(c)
     used = list(mesh.devices.flat) if mesh is not None else devices[:1]
 
@@ -374,8 +348,8 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     shardings = None
     if mesh is not None:
         from repro.sharding.policy import param_shardings
-        shardings = param_shardings(param_structs(c), mesh)
-    params = make_params(c, shardings)
+        shardings = param_shardings(param_structs(model, c), mesh)
+    params = make_params(model, c, shardings)
     jax.block_until_ready(params)
     log(f"weights: {cfg.num_layers} layers, d_model {cfg.d_model}, "
         f"{cfg.param_dtype}, drawn in {time.monotonic() - t:.3f} s")
@@ -424,7 +398,8 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
         reduced = trace_reduce.reduce_trace(trace_dir)
         if require_tpu:
             trace_reduce.require_step(reduced)
-        run =layer_run(records, marks, probe, reduced, c, len(used), peaks)
+        run = layer_run(records, marks, probe, reduced, cell, len(used),
+                        peaks)
         for name in names:
             value = spec.metric_reader(name)(run)
             if value is not None:
@@ -454,7 +429,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     t = time.monotonic()
     picked = check.sample(records, seed, min_tokens=mix["check_tokens"],
                           max_requests=mix["check_requests"])
-    gaps = check.served_gaps(params, c, picked, mix["engine"],
+    gaps = check.served_gaps(model, params, c, picked, mix["engine"],
                              control=control) if picked else {
         "served_logit_gap": None, "tokens_compared": 0,
         "requests_compared": 0}

@@ -9,7 +9,7 @@ def read(run):
     steps = [ctx for ctx in run["steps"] if ctx]
     if not prog or not prog["count"] or not steps:
         return None
-    work = sum(flops.verify_step(run["config"], ctx)["flops"]
+    work = sum(flops.verify_step(run, ctx)["flops"]
                for ctx in steps) / len(steps)
     peak = run["chips"] * run["peaks"]["bf16_flop_per_s"]
     return 100.0 * work / peak / (prog["seconds"] / prog["count"])

@@ -10,7 +10,7 @@ def read(run):
     steps = [ctx for ctx in run["steps"] if ctx]
     if not prog or not prog["count"] or not steps:
         return None
-    least = sum(flops.least_seconds(flops.verify_step(run["config"], ctx),
+    least = sum(flops.least_seconds(flops.verify_step(run, ctx),
                                     run["peaks"]) for ctx in steps)
     least /= len(steps) * run["chips"]
     return 100.0 * least / (prog["seconds"] / prog["count"])

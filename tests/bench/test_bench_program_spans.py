@@ -26,7 +26,8 @@ def test_traced_rehearsal_reads_the_host_gap():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     assert {m["name"] for m in bench["per_layer"]} >= set(NEW)
-    cell = spec.Cell(name="smoke", chips=1, config=config, traffic=mix,
+    cell = spec.Cell(name="smoke", chips=1, config=config,
+                     model=spec.load_model(config), traffic=mix,
                      limits={"served_logit_gap": {"limit": 0.01},
                              "tokens_compared": {"limit": 32}},
                      end_to_end=bench["end_to_end"],

@@ -36,7 +36,8 @@ def smoke_cell(loop="closed"):
     mix.update(loop=loop, name="smoke")
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    return spec.Cell(name="smoke", chips=1, config=config, traffic=mix,
+    return spec.Cell(name="smoke", chips=1, config=config,
+                     model=spec.load_model(config), traffic=mix,
                      limits={"served_logit_gap": {"limit": LIMIT},
                              "tokens_compared": {"limit": 32}},
                      end_to_end=bench["end_to_end"],
