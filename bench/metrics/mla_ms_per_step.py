@@ -1,0 +1,9 @@
+"""Model inside the step: device time of the ops under the ``mla.*`` scopes
+(latent projection, absorb, attend, output) per execution of the step
+program, from the ops' ``op_name`` in the trace (``bench.model_scopes``)
+(ms)."""
+from bench import model_scopes
+
+
+def read(run):
+    return model_scopes.prefix_ms_per_step(run, "mla.")

@@ -5,6 +5,7 @@ from repro.config.base import (
     MeshConfig,
     ModelConfig,
     TrainConfig,
+    YarnScaling,
 )
 from repro.config.registry import (
     get_config,
@@ -21,6 +22,7 @@ __all__ = [
     "MeshConfig",
     "ModelConfig",
     "TrainConfig",
+    "YarnScaling",
     "get_config",
     "get_policy",
     "list_archs",

@@ -17,6 +17,19 @@ DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16, "float16": jnp.float
 
 
 @dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN RoPE scaling (arXiv:2309.00071) as DeepSeek-V2 configures it
+    (its ``rope_scaling`` with ``type`` "yarn")."""
+
+    factor: float = 1.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     # --- identity -----------------------------------------------------------
     name: str = "model"
@@ -45,6 +58,15 @@ class ModelConfig:
     sliding_window: int = 0        # 0 = full attention
     global_attn_layers: Tuple[int, ...] = ()  # layers exempt from the window
     attn_logit_softcap: float = 0.0
+    rope_scaling: Optional[YarnScaling] = None
+
+    # --- multi-head latent attention (DeepSeek-V2 MLA; kv_lora_rank > 0) ----
+    # K/V are compressed to one kv_lora_rank latent per token plus one
+    # qk_rope_head_dim RoPE key shared by every head; the cache holds those.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # --- encoder / seq2seq ---------------------------------------------------
     is_encoder_only: bool = False
@@ -57,6 +79,11 @@ class ModelConfig:
     expert_pad_multiple: int = 1   # pad expert count so it shards on `model`
     num_shared_experts: int = 0
     shared_expert_d_ff: int = 0    # total width of the shared-expert MLP
+    shared_expert_gated: bool = True   # sigmoid gate on the shared experts
+    norm_topk_prob: bool = True        # renormalise the top-k gates to 1
+    routed_scaling_factor: float = 1.0  # gates' scale when not renormalised
+    first_k_dense_replace: int = 0     # leading layers with a dense MLP
+    dense_d_ff: int = 0                # their width (0 -> d_ff)
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.01
     router_z_coef: float = 1e-3
@@ -116,6 +143,17 @@ class ModelConfig:
         return self.bpd_hidden or min(self.d_ff, 4 * self.d_model)
 
     @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    def layer_mlp_type(self, layer_idx: int) -> str:
+        """The MLP of layer ``layer_idx``: a MoE stack's first
+        ``first_k_dense_replace`` layers are dense."""
+        if self.mlp_type == "moe" and layer_idx < self.first_k_dense_replace:
+            return "dense"
+        return self.mlp_type
+
+    @property
     def compute_dtype(self):
         return DTYPES[self.dtype]
 
@@ -138,6 +176,9 @@ class ModelConfig:
             )
         if self.mlp_type == "moe":
             assert self.num_experts > 0 and self.num_experts_per_tok > 0
+        if self.is_mla:
+            assert self.block_type == "attn" and self.qk_rope_head_dim % 2 == 0
+            assert self.qk_nope_head_dim > 0 and self.v_head_dim > 0
         if self.block_type == "rwkv6":
             assert self.d_model % self.rwkv_head_dim == 0
         if self.is_encoder_decoder:

@@ -1,6 +1,7 @@
 """Assigned architecture configs (``--arch <id>``).  Importing this package
 populates the registry."""
 from repro.configs import (  # noqa: F401
+    deepseek_v2_lite,
     granite_3_8b,
     hubert_xlarge,
     hymba_1_5b,
@@ -25,4 +26,5 @@ ASSIGNED = [
     "nemotron-4-15b",
     "olmoe-1b-7b",
     "granite-3-8b",
+    "deepseek-v2-lite",
 ]

@@ -77,6 +77,10 @@ class DraftModelDrafter(policy_lib.Drafter):
                 f"draft_cfg)}} to the DecodeSession / decode entry point "
                 f"(got bundles={sorted(bundles or {})})")
         d = b.cfg
+        if d.is_mla or (cfg is not None and cfg.is_mla):
+            raise NotImplementedError(
+                "the 'draft_model' policy is not implemented for latent "
+                "attention (MLA) models, as target or as draft")
         if d.block_type != "attn":
             raise NotImplementedError(
                 f"draft model {d.name!r} has block_type={d.block_type!r}: "

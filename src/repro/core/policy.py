@@ -378,6 +378,13 @@ class TopKTreeDrafter(Drafter):
 
     fanout: int = 4
 
+    def bind(self, bundles: Dict, cfg) -> "TopKTreeDrafter":
+        if cfg is not None and cfg.is_mla:
+            raise NotImplementedError(
+                f"{cfg.name}: tree verification is not implemented for "
+                f"latent attention (MLA); use a chain policy such as 'exact'")
+        return self
+
     def tree_topology(self, block_k: int):
         from repro.kernels.tree_mask import default_tree
 

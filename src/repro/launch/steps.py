@@ -192,6 +192,8 @@ def adapt_config(cfg: ModelConfig, shape_name: str) -> Optional[ModelConfig]:
     if shape_name == "long_500k":
         sub_quadratic = (cfg.block_type in ("rwkv6", "hymba")
                          or cfg.sliding_window)
+        if cfg.is_mla:
+            return None  # latent attention has no sliding-window form
         if not sub_quadratic:
             # dense/MoE/VLM: sliding-window variant (flagged, approximate)
             return cfg.replace(sliding_window=LONG_WINDOW)

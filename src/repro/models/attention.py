@@ -1,4 +1,5 @@
-"""GQA attention with RoPE, sliding windows, and BPD-aware KV caching.
+"""GQA attention with RoPE, sliding windows, and BPD-aware KV caching, and
+DeepSeek-V2's multi-head latent attention (MLA, ``cfg.kv_lora_rank > 0``).
 
 Three entry points:
   * ``attn_full``    — parallel forward over a whole sequence (training /
@@ -10,6 +11,12 @@ Three entry points:
 
 Masking is computed from absolute positions so the blockwise-parallel-decode
 rollback ("length decreases by up to k-1") needs no data movement.
+
+MLA (arXiv:2405.04434 §2.1; HF ``modeling_deepseek.py``) takes the same
+entry points: a model whose config has ``kv_lora_rank > 0`` attends through
+``mla_full`` (prefill, the expanded form) and ``mla_cached`` (the verify
+step, the absorbed form), and caches one normalised latent and one shared
+RoPE key per token (``models/cache.py``'s latent layout).
 """
 from __future__ import annotations
 
@@ -19,7 +26,16 @@ import jax
 import jax.numpy as jnp
 
 from repro.config import ModelConfig
-from repro.models.layers import apply_rope, dense_init, norm_apply, norm_init
+from repro.models.layers import (
+    apply_rope,
+    apply_rope_pairs,
+    dense_init,
+    norm_apply,
+    norm_init,
+    rope_freqs,
+    yarn_inv_freq,
+    yarn_mscale,
+)
 
 NEG_INF = -1e30
 
@@ -30,6 +46,8 @@ NEG_INF = -1e30
 
 
 def attn_init(key, cfg: ModelConfig, *, dtype=jnp.float32, cross: bool = False) -> Dict:
+    if cfg.is_mla and not cross:
+        return mla_init(key, cfg, dtype=dtype)
     d, hd = cfg.d_model, cfg.resolved_head_dim
     h, kv = cfg.num_heads, cfg.num_kv_heads
     ks = jax.random.split(key, 6)
@@ -124,6 +142,12 @@ def attn_full(p, cfg: ModelConfig, x, *, layer_idx: int = 0, positions=None,
     b, s, _ = x.shape
     if positions is None:
         positions = jnp.arange(s, dtype=jnp.int32)
+    if cfg.is_mla:
+        if bidirectional or kv_chunk:
+            raise NotImplementedError(
+                "latent attention (MLA) runs causal and unchunked only")
+        y, latent = mla_full(p, cfg, x, positions)
+        return (y, latent) if return_kv else y
     q, k, v = _project_qkv(p, cfg, x, positions, rope=not bidirectional)
     window = 0 if (bidirectional or layer_idx in cfg.global_attn_layers) else cfg.sliding_window
     if kv_chunk:
@@ -253,7 +277,10 @@ def cache_write(cache: Dict, cfg: ModelConfig, layer_idx: int, k, v, positions) 
     or through the block table (paged).
 
     positions: (S,) shared across rows (prefill) or (B, S) per-row (decode).
+    A latent (MLA) cache takes the latent as ``k`` and the RoPE key as ``v``.
     """
+    if "ckv" in cache:
+        return latent_cache_write(cache, k, v, positions)
     if "kp" in cache:
         return _paged_cache_write(cache, k, v, positions)
     buf_len = cache["k"].shape[1]
@@ -313,6 +340,9 @@ def attn_cached(p, cfg: ModelConfig, x_block, cache: Dict, length, *,
               cache.  After acceptance ``tree_commit_attn`` compacts the
               chosen root-to-leaf path back into chain slots.
     """
+    if cfg.is_mla:
+        return mla_cached(p, cfg, x_block, cache, length, kv_chunk=kv_chunk,
+                          tree=tree)
     b, kblk, _ = x_block.shape
     length = jnp.broadcast_to(jnp.asarray(length, jnp.int32), (b,))
     positions = length[:, None] + jnp.arange(kblk, dtype=jnp.int32)[None, :]
@@ -412,6 +442,160 @@ def tree_commit_attn(cache: Dict, cfg: ModelConfig, layer_idx: int,
 
     new["k"] = jax.vmap(row)(cache["k"], sslot, dslot, keep)
     new["v"] = jax.vmap(row)(cache["v"], sslot, dslot, keep)
+    return new
+
+
+# ---------------------------------------------------------------------------
+# Multi-head latent attention (DeepSeek-V2)
+# ---------------------------------------------------------------------------
+#
+#   q          = x Wq                              (H, nope + rope), no q LoRA
+#   [c, k_pe]  = x Wkv_a;  c = RMSNorm(c)          (kv_lora_rank), (rope)
+#   k_nope     = c Wkb,  v = c Wvb                 (H, nope), (H, v)
+#   score_h    = (q_nope·k_nope + q_pe·k_pe) · scale
+#   y          = softmax(score) v Wo
+#
+# q_pe and k_pe are RoPE'd at their positions, k_pe once for every head.
+# ``wkb`` / ``wvb`` are HF's ``kv_b_proj`` split into its key and value
+# halves.  The cache holds c and the RoPE'd k_pe.  The verify step uses the
+# absorbed form: q_nope Wkb^T is scored against the cached latents, and Wvb
+# is applied after the weighted sum of latents, so no cached latent is ever
+# expanded to per-head keys and values.
+
+
+def mla_init(key, cfg: ModelConfig, *, dtype=jnp.float32) -> Dict:
+    d, h, r = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    ks = jax.random.split(key, 5)
+    return {
+        "wq": dense_init(ks[0], d, h * (nope + rope), dtype=dtype)["w"]
+        .reshape(d, h, nope + rope),
+        "wkv_a": dense_init(ks[1], d, r + rope, dtype=dtype)["w"],
+        "kv_norm": norm_init(r, kind="rmsnorm", dtype=dtype),
+        "wkb": dense_init(ks[2], r, h * nope, dtype=dtype)["w"]
+        .reshape(r, h, nope),
+        "wvb": dense_init(ks[3], r, h * vd, dtype=dtype)["w"].reshape(r, h, vd),
+        "wo": dense_init(ks[4], h * vd, d, dtype=dtype)["w"].reshape(h, vd, d),
+    }
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """(nope + rope)^-1/2, times YaRN's mscale(mscale_all_dim) squared."""
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    ys = cfg.rope_scaling
+    if ys is not None and ys.mscale_all_dim:
+        scale *= yarn_mscale(ys.factor, ys.mscale_all_dim) ** 2
+    return scale
+
+
+def _mla_rope(cfg: ModelConfig):
+    """(inverse frequencies, cos/sin scale) of the decoupled RoPE dims."""
+    dim, ys = cfg.qk_rope_head_dim, cfg.rope_scaling
+    if ys is None:
+        return rope_freqs(dim, cfg.rope_theta), 1.0
+    return (yarn_inv_freq(dim, cfg.rope_theta, ys),
+            yarn_mscale(ys.factor, ys.mscale)
+            / yarn_mscale(ys.factor, ys.mscale_all_dim))
+
+
+def _mla_project(p, cfg: ModelConfig, x, positions):
+    """x: (B, S, d) -> q_nope (B,S,H,nope), q_pe (B,S,H,rope), the normed
+    latent c (B,S,r) and k_pe (B,S,rope); q_pe and k_pe RoPE'd."""
+    if cfg.sliding_window:
+        raise NotImplementedError(
+            "latent attention (MLA) has no sliding-window form")
+    nope, r = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    freqs, mscale = _mla_rope(cfg)
+    with jax.named_scope("mla.project"):
+        q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(x.dtype))
+        kv = x @ p["wkv_a"].astype(x.dtype)
+        c = norm_apply(p["kv_norm"], kv[..., :r])
+        q_pe = apply_rope_pairs(q[..., nope:], positions, freqs, mscale)
+        k_pe = apply_rope_pairs(kv[..., None, r:], positions, freqs,
+                                mscale)[..., 0, :]
+    return q[..., :nope], q_pe, c, k_pe
+
+
+def _mla_out(p, ctx):
+    with jax.named_scope("mla.out"):
+        return jnp.einsum("bshk,hkd->bsd", ctx, p["wo"].astype(ctx.dtype))
+
+
+def mla_full(p, cfg: ModelConfig, x, positions):
+    """Causal MLA over a whole sequence in the expanded form (prefill /
+    training).  Returns (y, (latent, k_pe)) — what the cache holds."""
+    q_nope, q_pe, c, k_pe = _mla_project(p, cfg, x, positions)
+    with jax.named_scope("mla.attend"):
+        k_nope = jnp.einsum("bsc,chk->bshk", c, p["wkb"].astype(c.dtype))
+        v = jnp.einsum("bsc,chk->bshk", c, p["wvb"].astype(c.dtype))
+        scores = (jnp.einsum("bqhk,bshk->bhqs", q_nope, k_nope)
+                  + jnp.einsum("bqhk,bsk->bhqs", q_pe, k_pe)
+                  ).astype(jnp.float32) * mla_softmax_scale(cfg)
+        mask = make_causal_mask(positions, positions)
+        if mask.ndim == 2:
+            mask = mask[None]
+        scores = jnp.where(mask[:, None], scores, NEG_INF)
+        probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+        ctx = jnp.einsum("bhqs,bshk->bqhk", probs, v)
+    return _mla_out(p, ctx), (c, k_pe)
+
+
+def mla_cached(p, cfg: ModelConfig, x_block, cache: Dict, length, *,
+               kv_chunk: int = 0, tree=None) -> Tuple[jnp.ndarray, Dict]:
+    """The verify step's MLA in the absorbed form: ``k`` fresh tokens at
+    positions length .. length+k-1 against the latent cache and each other
+    (the same contract as ``attn_cached``)."""
+    if tree is not None:
+        raise NotImplementedError(
+            "tree verification is not implemented for latent attention "
+            "(MLA): its cache has no tree-commit path")
+    if kv_chunk:
+        raise NotImplementedError(
+            "latent attention (MLA) has no chunked (kv_chunk) form")
+    b, kblk, _ = x_block.shape
+    length = jnp.broadcast_to(jnp.asarray(length, jnp.int32), (b,))
+    positions = length[:, None] + jnp.arange(kblk, dtype=jnp.int32)[None, :]
+    q_nope, q_pe, c, k_pe = _mla_project(p, cfg, x_block, positions)
+    cache = latent_cache_write(cache, c, k_pe, positions)
+    kv_pos = cache["pos"]
+    kv_pos = jnp.where(kv_pos < (length + kblk)[:, None], kv_pos, -1)
+    mask = make_causal_mask(positions, kv_pos)                     # (B, k, L)
+    ckv, kpe = cache["ckv"], cache["kpe"]
+    with jax.named_scope("mla.absorb"):
+        q_lat = jnp.einsum("bqhk,chk->bqhc", q_nope,
+                           p["wkb"].astype(q_nope.dtype))          # (B,k,H,r)
+    with jax.named_scope("mla.attend"):
+        scores = (jnp.einsum("bqhc,bsc->bhqs", q_lat, ckv.astype(q_lat.dtype))
+                  + jnp.einsum("bqhk,bsk->bhqs", q_pe, kpe.astype(q_pe.dtype))
+                  ).astype(jnp.float32) * mla_softmax_scale(cfg)
+        scores = jnp.where(mask[:, None], scores, NEG_INF)
+        probs = jax.nn.softmax(scores, axis=-1).astype(q_lat.dtype)
+        ctx_lat = jnp.einsum("bhqs,bsc->bqhc", probs, ckv.astype(probs.dtype))
+    with jax.named_scope("mla.absorb"):
+        ctx = jnp.einsum("bqhc,chk->bqhk", ctx_lat,
+                         p["wvb"].astype(ctx_lat.dtype))
+    return _mla_out(p, ctx), cache
+
+
+def latent_cache_write(cache: Dict, c, k_pe, positions) -> Dict:
+    """Write latents ``c`` (B,S,r) and RoPE keys ``k_pe`` (B,S,rope) at
+    ``positions`` ((S,) shared or (B, S) per row) into a latent cache; full
+    attention, so a position's slot is the position itself."""
+    b = cache["ckv"].shape[0]
+    if positions.ndim == 1:
+        positions = jnp.broadcast_to(positions[None, :],
+                                     (b, positions.shape[0]))
+    slots = positions.astype(jnp.int32)
+
+    def row_write(buf, slot, val):
+        return buf.at[slot].set(val)
+
+    new = dict(cache)
+    new["ckv"] = jax.vmap(row_write)(cache["ckv"], slots,
+                                     c.astype(cache["ckv"].dtype))
+    new["kpe"] = jax.vmap(row_write)(cache["kpe"], slots,
+                                     k_pe.astype(cache["kpe"].dtype))
+    new["pos"] = jax.vmap(row_write)(cache["pos"], slots, slots)
     return new
 
 

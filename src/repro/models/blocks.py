@@ -5,7 +5,8 @@ One ``Block`` covers every assigned family via ``cfg.block_type`` ×
 
   attn  + dense     : stablelm / starcoder2 / nemotron / granite / llava /
                       hubert (bidirectional) / the paper's MT transformer
-  attn  + moe       : qwen2-moe, olmoe
+  attn  + moe       : qwen2-moe, olmoe, deepseek-v2-lite (latent attention,
+                      dense layers below ``first_k_dense_replace``)
   rwkv6 + channel   : rwkv6 ("Finch")
   hymba + dense     : hymba (parallel attention + mamba heads, fused)
 
@@ -75,11 +76,13 @@ def block_init(key, cfg: ModelConfig, layer_idx: int, *, dtype=jnp.float32,
         p["cross"] = cross_attn_init(ks[2], cfg, dtype=dtype)
 
     p["ln2"] = norm_init(cfg.d_model, kind=cfg.norm_type, dtype=dtype)
-    if cfg.mlp_type == "dense":
-        p["mlp"] = mlp_init(ks[3], cfg, dtype=dtype)
-    elif cfg.mlp_type == "moe":
+    mlp_type = cfg.layer_mlp_type(layer_idx)
+    if mlp_type == "dense":
+        p["mlp"] = mlp_init(ks[3], cfg, d_ff=cfg.dense_d_ff or None,
+                            dtype=dtype)
+    elif mlp_type == "moe":
         p["moe"] = moe_init(ks[3], cfg, dtype=dtype)
-    elif cfg.mlp_type == "rwkv_channel_mix":
+    elif mlp_type == "rwkv_channel_mix":
         p["cm"] = rwkv_cm_init(ks[3], cfg, dtype=dtype)
     else:
         raise ValueError(cfg.mlp_type)
@@ -166,9 +169,10 @@ def block_full(p, cfg: ModelConfig, layer_idx: int, x, *, positions=None,
         x = x + cross_attn_apply(p["cross"], cfg, h, enc_kv, enc_mask)
 
     h = norm_apply(p["ln2"], x, kind=cfg.norm_type)
-    if cfg.mlp_type == "dense":
+    mlp_type = cfg.layer_mlp_type(layer_idx)
+    if mlp_type == "dense":
         y = mlp_apply(p["mlp"], h, act=cfg.activation)
-    elif cfg.mlp_type == "moe":
+    elif mlp_type == "moe":
         y, metrics = moe_apply(p["moe"], cfg, h, full_capacity=moe_full_capacity)
     else:  # rwkv channel mix
         y, cm_aux = rwkv_cm_apply(p["cm"], cfg, h)
@@ -247,9 +251,10 @@ def block_cached(p, cfg: ModelConfig, layer_idx: int, x, cache: Dict, length,
         x = x + cross_attn_apply(p["cross"], cfg, h, enc_kv, enc_mask)
 
     h = norm_apply(p["ln2"], x, kind=cfg.norm_type)
-    if cfg.mlp_type == "dense":
+    mlp_type = cfg.layer_mlp_type(layer_idx)
+    if mlp_type == "dense":
         y = mlp_apply(p["mlp"], h, act=cfg.activation)
-    elif cfg.mlp_type == "moe":
+    elif mlp_type == "moe":
         y, _ = moe_apply(p["moe"], cfg, h, full_capacity=True)
     else:
         y, _ = rwkv_cm_apply(p["cm"], cfg, h,

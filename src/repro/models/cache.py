@@ -8,6 +8,11 @@ Dense KV layout (per attention layer, ``cache_backend="dense"``):
     pos  : (batch, buf_len) int32                 absolute position held by
                                                   slot (-1 = never written)
 
+Dense latent layout (per MLA layer, ``cfg.kv_lora_rank > 0``; dense only):
+    ckv  : (batch, buf_len, kv_lora_rank)      normalised KV latent
+    kpe  : (batch, buf_len, qk_rope_head_dim)  post-RoPE key shared by heads
+    pos  : (batch, buf_len) int32              as above
+
 Paged KV layout (per full-attention layer, ``cache_backend="paged"``):
     kp, vp : (num_pages, page_size, kv_heads, head_dim)  shared page pool
     tbl    : (batch, P) int32       per-row block table: logical page i of
@@ -57,6 +62,15 @@ def attn_cache_init(batch: int, buf_len: int, kv_heads: int, head_dim: int, dtyp
         "v": jnp.zeros((batch, buf_len, kv_heads, head_dim), dtype),
         # per-row absolute positions: rows advance at different rates under
         # blockwise parallel decoding (per-row accepted block sizes)
+        "pos": jnp.full((batch, buf_len), -1, jnp.int32),
+    }
+
+
+def latent_cache_init(batch: int, buf_len: int, rank: int, rope_dim: int,
+                      dtype) -> Dict:
+    return {
+        "ckv": jnp.zeros((batch, buf_len, rank), dtype),
+        "kpe": jnp.zeros((batch, buf_len, rope_dim), dtype),
         "pos": jnp.full((batch, buf_len), -1, jnp.int32),
     }
 
@@ -320,8 +334,18 @@ class DenseBackend(KVCacheBackend):
     def layer_attn_init(self, cfg: ModelConfig, layer_idx: int, batch: int,
                         context_len: int, block_k: int, dtype) -> Dict:
         buf = attn_buf_len(cfg, layer_idx, context_len, block_k)
+        if cfg.is_mla:
+            return latent_cache_init(batch, buf, cfg.kv_lora_rank,
+                                     cfg.qk_rope_head_dim, dtype)
         return attn_cache_init(batch, buf, cfg.num_kv_heads,
                                cfg.resolved_head_dim, dtype)
+
+
+def _refuse_latent(cfg: ModelConfig) -> None:
+    if cfg.is_mla:
+        raise NotImplementedError(
+            f"{cfg.name}: the paged cache pages per-head K/V and has no "
+            f"latent (MLA) layout; serve it with cache_backend='dense'")
 
 
 class _PagedRowBackend(DenseBackend):
@@ -336,6 +360,7 @@ class _PagedRowBackend(DenseBackend):
 
     def layer_attn_init(self, cfg, layer_idx, batch, context_len, block_k,
                         dtype):
+        _refuse_latent(cfg)
         if _is_window_layer(cfg, layer_idx):
             return super().layer_attn_init(cfg, layer_idx, batch, context_len,
                                            block_k, dtype)
@@ -372,6 +397,7 @@ class PagedBackend(KVCacheBackend):
 
     def layer_attn_init(self, cfg: ModelConfig, layer_idx: int, batch: int,
                         context_len: int, block_k: int, dtype) -> Dict:
+        _refuse_latent(cfg)
         if _is_window_layer(cfg, layer_idx):
             buf = attn_buf_len(cfg, layer_idx, context_len, block_k)
             return attn_cache_init(batch, buf, cfg.num_kv_heads,

@@ -6,6 +6,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.config import ModelConfig
 
@@ -94,14 +95,60 @@ def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
 
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
     """x: (..., seq, heads, head_dim); positions: (..., seq) int32."""
-    head_dim = x.shape[-1]
-    freqs = rope_freqs(head_dim, theta)  # (hd/2,)
+    return _rotate_halves(x, positions, rope_freqs(x.shape[-1], theta))
+
+
+def _rotate_halves(x, positions, freqs, scale: Optional[float] = None):
+    """Rotate the first half of the last dim against the second by
+    ``positions * freqs``; cos and sin are multiplied by ``scale``."""
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # (..., seq, hd/2)
     cos = jnp.cos(angles)[..., :, None, :]  # broadcast over heads
     sin = jnp.sin(angles)[..., :, None, :]
+    if scale is not None:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     y = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return y.astype(x.dtype)
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature (DeepSeek-V2's ``yarn_get_mscale``)."""
+    if factor <= 1.0:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, s) -> np.ndarray:
+    """YaRN inverse frequencies for a rotary dim ``dim`` (``s``: a
+    ``config.YarnScaling``), as DeepSeek-V2's YaRN rotary embedding makes
+    them: dims that turn more than ``beta_fast`` times over the original
+    context keep their frequency, those that turn fewer than ``beta_slow``
+    times are divided by ``factor``, and a linear ramp joins the two."""
+    def correction_dim(turns: float) -> float:
+        return (dim * math.log(s.original_max_position_embeddings
+                               / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(s.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(s.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp
+    return (extra / s.factor * (1.0 - keep) + extra * keep).astype(np.float32)
+
+
+def apply_rope_pairs(x: jnp.ndarray, positions: jnp.ndarray, freqs,
+                     scale: float = 1.0) -> jnp.ndarray:
+    """RoPE as DeepSeek-V2 applies it (``apply_rotary_pos_emb`` of HF
+    ``modeling_deepseek.py``): the last dim is first de-interleaved,
+    [x0, x2, x4, .., x1, x3, ..], then rotated by halves with cos and sin
+    times ``scale``.  x: (..., seq, heads, dim); positions: (..., seq)."""
+    d = x.shape[-1]
+    x = x.reshape(x.shape[:-1] + (d // 2, 2)).swapaxes(-1, -2).reshape(x.shape)
+    return _rotate_halves(x, positions, jnp.asarray(freqs), scale)
 
 
 # ---------------------------------------------------------------------------

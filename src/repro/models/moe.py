@@ -1,15 +1,21 @@
 """Mixture-of-Experts MLP with capacity-bounded top-k routing.
 
-Dispatch uses a sort-based rank computation plus scatter/gather (MaxText /
+Dispatch uses a sort-based rank computation plus index gathers (MaxText /
 MegaBlocks style) rather than the classic one-hot einsum: the einsum
 formulation is O(T·E·C) memory, which at train_4k scale (1M tokens, 60
-experts) is petabytes; the scatter formulation is O(T·K·d).  Under ``pjit``
+experts) is petabytes; the gathers move O(T·K·d).  Under ``pjit``
 with experts sharded over the ``model`` mesh axis GSPMD lowers the
-scatter/gather across the expert dim to all-to-all-style collectives.
+gathers across the expert dim to all-to-all-style collectives.
 
-Covers both assigned MoE architectures:
-  * qwen2-moe-a2.7b: 60 routed experts top-4 + 4 shared experts (sigmoid gate)
-  * olmoe-1b-7b:     64 routed experts top-8, no shared experts
+Covers the registered MoE architectures:
+  * qwen2-moe-a2.7b:  60 routed experts top-4 + 4 shared experts (sigmoid gate)
+  * olmoe-1b-7b:      64 routed experts top-8, no shared experts
+  * deepseek-v2-lite: 64 routed experts top-6 without renormalisation
+                      (``norm_topk_prob`` false, gates times
+                      ``routed_scaling_factor``) + 2 ungated shared experts
+
+Named scopes ``moe.route``, ``moe.dispatch``, ``moe.experts``,
+``moe.combine`` and ``moe.shared`` mark the parts in a device trace.
 """
 from __future__ import annotations
 
@@ -27,7 +33,7 @@ def moe_init(key, cfg: ModelConfig, *, dtype=jnp.float32) -> Dict:
     d, ff, e = cfg.d_model, cfg.d_ff, cfg.num_experts
     ep = cfg.padded_num_experts      # expert weights padded so E shards
     ks = jax.random.split(key, 6)
-    std = 1.0 / jnp.sqrt(d).astype(jnp.float32)
+    std = (1.0 / jnp.sqrt(d).astype(jnp.float32)).astype(dtype)
     p = {
         "router": dense_init(ks[0], d, e, dtype=dtype),
         "w1": jax.random.normal(ks[1], (ep, d, ff), dtype) * std,
@@ -41,8 +47,10 @@ def moe_init(key, cfg: ModelConfig, *, dtype=jnp.float32) -> Dict:
             "w1": dense_init(ks[4], d, sff, dtype=dtype),
             "w3": dense_init(ks[5], d, sff, dtype=dtype),
             "w2": dense_init(jax.random.fold_in(key, 7), sff, d, dtype=dtype),
-            "gate": dense_init(jax.random.fold_in(key, 8), d, 1, dtype=dtype),
         }
+        if cfg.shared_expert_gated:
+            p["shared"]["gate"] = dense_init(jax.random.fold_in(key, 8), d, 1,
+                                             dtype=dtype)
     return p
 
 
@@ -94,11 +102,16 @@ def moe_apply(p, cfg: ModelConfig, x, *, full_capacity: bool = False
     e, topk = cfg.num_experts, cfg.num_experts_per_tok
     ep = cfg.padded_num_experts
 
-    logits = dense_apply(p["router"], x.astype(jnp.float32))         # (B, S, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, expert_ids = jax.lax.top_k(probs, topk)               # (B, S, K)
-    gate_vals = gate_vals / jnp.maximum(
-        jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)            # renorm
+    with jax.named_scope("moe.route"):
+        logits = dense_apply(p["router"], x.astype(jnp.float32))     # (B, S, E)
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate_vals, expert_ids = jax.lax.top_k(probs, topk)           # (B, S, K)
+        if cfg.norm_topk_prob:
+            gate_vals = gate_vals / jnp.maximum(
+                jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)    # renorm
+        elif cfg.routed_scaling_factor != 1.0:
+            # DeepSeek-V2 scales the gates only where it does not renormalise
+            gate_vals = gate_vals * cfg.routed_scaling_factor
 
     if full_capacity:
         capacity = s  # a row's expert gets at most one slot per token
@@ -107,21 +120,31 @@ def moe_apply(p, cfg: ModelConfig, x, *, full_capacity: bool = False
     capacity = min(capacity, s)
 
     def dispatch_row(xr, er):
-        """xr: (S, d); er: (S, K) -> per-group expert buffer + slots."""
+        """xr: (S, d); er: (S, K) -> per-group expert buffer + slots.
+
+        The buffer is gathered: each of its Ep*Cg rows names the token that
+        fills it (kept slots are distinct), or a zero row.  The TPU compile
+        keeps a gather's ``op_name`` (a scatter-add and its zero fill lose
+        theirs), so a trace charges the buffer to ``moe.dispatch``.
+        """
         rank = _assignment_ranks(er.reshape(s * topk)).reshape(s, topk)
         keep = rank < capacity
         # destination in the (Ep*Cg) buffer; capacity overflow -> dump row
         slot = jnp.where(keep, er * capacity + rank, ep * capacity)
-        xin = jnp.zeros((ep * capacity + 1, d), xr.dtype)
-        xin = xin.at[slot.reshape(-1)].add(
-            jnp.broadcast_to(xr[:, None, :], (s, topk, d)).reshape(-1, d))
-        return xin[: ep * capacity].reshape(ep, capacity, d), slot, keep
+        src = jnp.full((ep * capacity + 1,), s, jnp.int32).at[
+            slot.reshape(-1)].set(jnp.repeat(jnp.arange(s, dtype=jnp.int32),
+                                             topk))
+        xpad = jnp.concatenate([xr, jnp.zeros((1, d), xr.dtype)], 0)
+        xin = jnp.take(xpad, src[: ep * capacity], axis=0)
+        return xin.reshape(ep, capacity, d), slot, keep
 
-    xin, slot, keep = jax.vmap(dispatch_row)(x, expert_ids)  # (B, Ep, Cg, d)
-    xin = maybe_shard_expert(xin)
+    with jax.named_scope("moe.dispatch"):
+        xin, slot, keep = jax.vmap(dispatch_row)(x, expert_ids)  # (B,Ep,Cg,d)
+        xin = maybe_shard_expert(xin)
 
-    xout = _expert_ffn(p, xin, cfg.activation)                # (B, Ep, Cg, d)
-    xout = maybe_shard_expert(xout)
+    with jax.named_scope("moe.experts"):
+        xout = _expert_ffn(p, xin, cfg.activation)            # (B, Ep, Cg, d)
+        xout = maybe_shard_expert(xout)
 
     def gather_row(xo, sl, gv, kp):
         flat = jnp.concatenate(
@@ -130,15 +153,20 @@ def moe_apply(p, cfg: ModelConfig, x, *, full_capacity: bool = False
         w = (gv * kp.astype(gv.dtype)).astype(xo.dtype)
         return jnp.einsum("skd,sk->sd", g, w)
 
-    y = jax.vmap(gather_row)(xout, slot, gate_vals, keep)     # (B, S, d)
+    with jax.named_scope("moe.combine"):
+        y = jax.vmap(gather_row)(xout, slot, gate_vals, keep)  # (B, S, d)
 
     if "shared" in p:
-        sp = p["shared"]
-        h = activation("silu", dense_apply(sp["w1"], x)) * dense_apply(sp["w3"], x)
-        shared_out = dense_apply(sp["w2"], h)
-        g = jax.nn.sigmoid(dense_apply(sp["gate"], x).astype(jnp.float32)
-                           ).astype(x.dtype)
-        y = y + g * shared_out
+        with jax.named_scope("moe.shared"):
+            sp = p["shared"]
+            h = (activation("silu", dense_apply(sp["w1"], x))
+                 * dense_apply(sp["w3"], x))
+            shared_out = dense_apply(sp["w2"], h)
+            if "gate" in sp:
+                g = jax.nn.sigmoid(dense_apply(sp["gate"], x).astype(
+                    jnp.float32)).astype(x.dtype)
+                shared_out = g * shared_out
+            y = y + shared_out
 
     # Switch-Transformer load-balance loss + router z-loss
     t = b * s

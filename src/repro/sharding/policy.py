@@ -41,6 +41,11 @@ PARAM_RULES: Tuple[Tuple[str, P], ...] = (
     (r"(attn|cross)/wk$", P(None, "model", None)),
     (r"(attn|cross)/wv$", P(None, "model", None)),
     (r"(attn|cross)/wo$", P("model", None, None)),
+    # MLA: the latent projection serves every head; its up-projections
+    # (the halves of kv_b_proj) split by head like wq
+    (r"attn/wkv_a$", P()),
+    (r"attn/wkb$", P(None, "model", None)),
+    (r"attn/wvb$", P(None, "model", None)),
     # --- MoE -------------------------------------------------------------------
     (r"moe/router/", P()),
     (r"moe/w1$", P("model", None, None)),
@@ -210,6 +215,9 @@ def cache_specs(cfg: ModelConfig, caches: Pytree, mesh: Mesh,
             if kv_divides:
                 return _divisible(P(None, None, "model", None), x.shape, mesh)
             return P()
+        if "/attn/" in name and name.endswith(("/ckv", "/kpe")):
+            # MLA latent: one per token, shared by every head
+            return P(ax, None, None)
         if name.endswith("/tbl"):
             return P(ax, None)
         if name.endswith("/pos"):

@@ -24,7 +24,7 @@ import numpy as np
 import pytest
 
 from conftest import tiny_dense, tiny_seq2seq
-from repro.config import DecodeConfig
+from repro.config import DecodeConfig, get_config
 from repro.core import decode as D
 from repro.launch.mesh import make_host_mesh
 from repro.models import model as M
@@ -300,6 +300,43 @@ def test_engine_sharded_midflight_admission(mesh, dense):
     # compile-once survives sharding (admit twice, step many, evict twice)
     assert all(v == 1 for v in eng.compile_counts().values()), \
         eng.compile_counts()
+
+
+def test_engine_sharded_latent_attention_moe(mesh):
+    """DeepSeek-V2's smoke model (latent cache, routed and shared experts,
+    a dense first layer) serves the same tokens on the (2, 2) mesh as on
+    one device, a mid-flight admission included; its per-head MLA
+    up-projections split on the model axis, its latent cache on data."""
+    cfg = get_config("deepseek-v2-lite", smoke=True).replace(dtype="float32")
+    params = M.init(jax.random.PRNGKey(0), cfg)
+    dec = DecodeConfig(max_new_tokens=12, block_k=4)
+    rng = np.random.default_rng(5)
+    p0 = rng.integers(0, cfg.vocab_size, size=8)
+    p1 = rng.integers(0, cfg.vocab_size, size=5)
+
+    def serve(mesh_):
+        eng = ContinuousBatchingEngine(
+            params, cfg, dec,
+            EngineConfig(num_slots=4, max_prompt_len=8, max_new_cap=12),
+            mesh=mesh_)
+        done = []
+        eng.admit(Request(rid=0, prompt=p0, max_new=12))
+        for _ in range(2):
+            done += eng.step()
+        eng.admit(Request(rid=1, prompt=p1, max_new=10))
+        while eng.has_active():
+            done += eng.step()
+        return eng, {f.rid: np.asarray(f.tokens) for f in done}
+
+    eng, sharded = serve(mesh)
+    _, local = serve(None)
+    assert sorted(sharded) == [0, 1]
+    for rid in (0, 1):
+        np.testing.assert_array_equal(sharded[rid], local[rid])
+    attn = eng.session.params["blocks"][1]["attn"]
+    assert "model" in _spec_axes(attn["wkb"].sharding)
+    assert "model" in _spec_axes(attn["wvb"].sharding)
+    assert "data" in _spec_axes(eng.state.caches[1]["attn"]["ckv"].sharding)
 
 
 def test_engine_config_mesh_validation(mesh, dense):

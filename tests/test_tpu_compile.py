@@ -12,7 +12,9 @@ one process at a time may load the TPU library, and every test worker
 imports every test file.
 """
 import functools
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,7 +23,9 @@ import pytest
 from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec,
                           SingleDeviceSharding)
 
+from repro.config.registry import get_config
 from repro.kernels import ops
+from repro.models import moe
 
 B, KQ, H, KV, HD, L, PAGE = 8, 8, 32, 8, 128, 2048, 16
 VERIFY_ROWS, VOCAB = 16, 49408
@@ -105,3 +109,53 @@ def test_paged_verify_attention_compiles(spec):
     _compile(fn, spec((B, KQ, H, HD), jnp.bfloat16), pool, pool,
              spec((B, pages), jnp.int32), spec((B, KQ), jnp.int32),
              spec((B, L), jnp.int32))
+
+
+# Memory-space moves the TPU compiler adds on its own (weights prefetched
+# in slices, reassembled by ConcatBitcast): no op of the model's, no scope.
+_COMPILER_MOVES = ("copy-start", "copy-done", "slice-start", "slice-done")
+
+
+def _unscoped_large_ops(hlo: str, prefix: str, min_bytes: int):
+    """Instructions of the entry computation that write a floating-point
+    array of at least ``min_bytes`` and carry no ``op_name`` with a
+    component starting with ``prefix``."""
+    entry = hlo[hlo.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    itemsize = {"bf16": 2, "f16": 2, "f32": 4}
+    out = []
+    for line in entry.splitlines()[1:]:
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (bf16|f16|f32)\[([\d,]*)\]\S* "
+                     r"([\w-]+)\(", line)
+        if not m or m.group(3) in _COMPILER_MOVES + ("parameter", "bitcast") or (
+                'custom_call_target="ConcatBitcast"' in line):
+            continue
+        size = itemsize[m.group(1)] * math.prod(
+            int(n) for n in m.group(2).split(",") if n)
+        name = re.search(r'op_name="([^"]*)"', line)
+        if size >= min_bytes and not (name and any(
+                part.startswith(prefix) for part in name.group(1).split("/"))):
+            out.append(line.strip()[:160])
+    return out
+
+
+def test_moe_layer_ops_keep_their_scopes(spec):
+    """Every op of a DeepSeek-V2-Lite MoE layer that writes more than its
+    input (≥ 4 MiB), at the published widths and the benchmark's
+    verify-step shape (32 rows of 8), carries a ``moe.*`` name after the
+    TPU compile, so a trace charges the dispatch to ``moe.dispatch``.  A
+    scatter-add into a zeroed buffer lost its ``op_name`` here (the zero
+    fill and the scatter fusion, 67 MB each a layer)."""
+    cfg = get_config("deepseek-v2-lite")
+    params = jax.tree.map(
+        lambda a: spec(a.shape, a.dtype),
+        jax.eval_shape(lambda k: moe.moe_init(k, cfg, dtype=jnp.bfloat16),
+                       jax.random.PRNGKey(0)))
+
+    def layer(p, x):
+        return moe.moe_apply(p, cfg, x, full_capacity=True)[0]
+
+    hlo = jax.jit(layer).lower(
+        params, spec((32, 8, cfg.d_model), jnp.bfloat16)).compile().as_text()
+    assert "moe.dispatch" in hlo and "moe.experts" in hlo
+    assert _unscoped_large_ops(hlo, "moe.", 4 << 20) == []
