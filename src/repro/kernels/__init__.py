@@ -10,6 +10,9 @@
                            compare + prefix-accept scan
   * ``tree_mask``        — candidate-tree topologies (ancestor masks,
                            packed bitmasks) for tree verification
+  * ``ops.grouped_matmul`` — rows sorted by group times their group's
+                           weights (JAX's megablox ``gmm``): the MoE's
+                           no-drop expert products
 
 ``ops`` holds the jit'd wrappers (interpret mode on CPU); ``ref`` the
 oracles used by the per-kernel shape/dtype sweep tests.
@@ -18,6 +21,7 @@ from repro.kernels import ops, ref  # noqa: F401
 from repro.kernels.ops import (  # noqa: F401
     fused_heads_topk,
     fused_verify,
+    grouped_matmul,
     paged_verify_attention,
     rwkv6_scan,
     tree_verify_attention,

@@ -11,6 +11,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu.megablox import gmm
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels.block_attention import (
@@ -114,3 +115,52 @@ def fused_heads_topk(o, w_vocab, *, vocab: int, top_t: int = 4,
     return fused_heads_topk_pallas(o, w_vocab, vocab=vocab, top_t=top_t,
                                    block_rows=block_rows, block_v=block_v,
                                    interpret=interp)
+
+
+def _gmm_tiling(m: int, k: int, n: int):
+    """(tm, tk, tn) for ``gmm``: row tiles of 128 (m is a multiple, or
+    less), weight blocks of up to 512 × 2048; the fastest of six tilings
+    at DeepSeek-V2-Lite's widths on a TPU v5e (PERF.md §6)."""
+    return min(m, 128), min(k, 512), min(n, 2048)
+
+
+def grouped_matmul(lhs, rhs, group_sizes):
+    """lhs (m, k), rows sorted by group; rhs (G, k, n); group_sizes (G,)
+    int32 summing to m -> (m, n) in lhs's dtype: each row times its own
+    group's rhs, accumulated in float32 (megablox ``gmm``).  Each group's
+    weights are read once per row tile it touches; empty groups cost
+    nothing.  The kernel runs interpreted off the TPU, chosen by the
+    platform the program is lowered for, so an ahead-of-time compile for
+    a TPU gets the kernel itself."""
+    m = lhs.shape[0]
+    tm = min(128, -(-m // 16) * 16)     # rows per tile: 16k, at most 128
+    lhs = jnp.pad(lhs, ((0, -(-m // tm) * tm - m), (0, 0)))
+    rhs = rhs.astype(lhs.dtype)
+
+    def product(lhs, rhs, group_sizes, offset):
+        def run(interpret):
+            return functools.partial(
+                gmm, preferred_element_type=lhs.dtype, tiling=_gmm_tiling,
+                group_offset=offset, interpret=interpret)
+        return jax.lax.platform_dependent(lhs, rhs, group_sizes,
+                                          tpu=run(False), default=run(True))
+
+    mesh = active_mesh()
+    if mesh is None:
+        return product(lhs, rhs, group_sizes, None)[:m]
+    # GSPMD cannot partition a Mosaic kernel: each model shard multiplies
+    # the rows of its own groups (gmm zeroes the others) and the shards'
+    # outputs are summed
+    ax = "model" if ("model" in mesh.axis_names
+                     and rhs.shape[0] % mesh.shape["model"] == 0) else None
+    local = rhs.shape[0] // (mesh.shape["model"] if ax else 1)
+
+    def shard(lhs, rhs, group_sizes):
+        if ax is None:
+            return product(lhs, rhs, group_sizes, None)
+        offset = (jax.lax.axis_index(ax) * local).astype(jnp.int32)
+        return jax.lax.psum(product(lhs, rhs, group_sizes, offset), ax)
+
+    out = jax.shard_map(shard, mesh=mesh, in_specs=(P(), P(ax), P()),
+                        out_specs=P(), check_vma=False)(lhs, rhs, group_sizes)
+    return out[:m]

@@ -1,11 +1,18 @@
-"""Mixture-of-Experts MLP with capacity-bounded top-k routing.
+"""Mixture-of-Experts MLP: top-k routing, then one of two dispatches.
 
-Dispatch uses a sort-based rank computation plus index gathers (MaxText /
-MegaBlocks style) rather than the classic one-hot einsum: the einsum
-formulation is O(T·E·C) memory, which at train_4k scale (1M tokens, 60
-experts) is petabytes; the gathers move O(T·K·d).  Under ``pjit``
-with experts sharded over the ``model`` mesh axis GSPMD lowers the
-gathers across the expert dim to all-to-all-style collectives.
+* No-drop (``full_capacity=True``: the serving engine's verify step and
+  admissions, ``bpd_decode``/``greedy_decode`` prefills, the draft model):
+  the B·S·K assignments are stably sorted by expert, their token rows
+  gathered in that order, and the experts run as three grouped products
+  (``kernels.ops.grouped_matmul``) over exactly those rows; the combine
+  gathers them back and takes each token's gate-weighted sum.  Nothing is
+  dropped, as blockwise parallel decoding's greedy equivalence needs.
+* Capacity-bounded (training): GShard's per-row buffer of (B, Ep, Cg, d),
+  built by a sort-based rank and index gathers (MaxText / MegaBlocks
+  style) rather than the O(T·E·C) one-hot einsum, which at train_4k scale
+  (1M tokens, 60 experts) is petabytes.  Assignments past an expert's
+  capacity are dropped; with experts sharded over the ``model`` mesh axis
+  GSPMD lowers the buffer's redistribution to an all-to-all on (B, Ep).
 
 Covers the registered MoE architectures:
   * qwen2-moe-a2.7b:  60 routed experts top-4 + 4 shared experts (sigmoid gate)
@@ -25,6 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.config import ModelConfig
+from repro.kernels.ops import grouped_matmul
 from repro.models.layers import GATED_ACTIVATIONS, activation, dense_apply, dense_init
 from repro.sharding.policy import maybe_shard_expert
 
@@ -83,41 +91,18 @@ def _assignment_ranks(expert_ids_flat: jnp.ndarray) -> jnp.ndarray:
     return jnp.zeros((a,), jnp.int32).at[order].set(rank_sorted)
 
 
-def moe_apply(p, cfg: ModelConfig, x, *, full_capacity: bool = False
-              ) -> Tuple[jnp.ndarray, Dict]:
-    """x: (B, S, d) -> (y, metrics).
+def _capacity_dispatch(p, cfg: ModelConfig, x, expert_ids, gate_vals):
+    """The capacity-bounded path: x (B, S, d) -> (y, keep).
 
-    GShard-style GROUPED dispatch: each batch row is a dispatch group with
-    its own capacity, so the expert buffer is (B, Ep, Cg, d) — batch-sharded
-    over `data`, expert-sharded over `model` — and the data→expert
-    redistribution lowers to an all-to-all on those two dims instead of
-    replicating the token array per expert shard (measured: 2.56 TB → GB-
-    scale collectives at prefill_32k; EXPERIMENTS.md §Perf #3).
-
-    full_capacity=True sets capacity so no token can ever be dropped — used
-    on the decode path, where dropping would break the paper's greedy-
-    equivalence guarantee for blockwise parallel decoding.
+    Each batch row is a dispatch group with its own capacity, so the expert
+    buffer is (B, Ep, Cg, d), batch-sharded over `data` and expert-sharded
+    over `model`; assignments past an expert's capacity are dropped
+    (``keep`` false).
     """
     b, s, d = x.shape
     e, topk = cfg.num_experts, cfg.num_experts_per_tok
     ep = cfg.padded_num_experts
-
-    with jax.named_scope("moe.route"):
-        logits = dense_apply(p["router"], x.astype(jnp.float32))     # (B, S, E)
-        probs = jax.nn.softmax(logits, axis=-1)
-        gate_vals, expert_ids = jax.lax.top_k(probs, topk)           # (B, S, K)
-        if cfg.norm_topk_prob:
-            gate_vals = gate_vals / jnp.maximum(
-                jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)    # renorm
-        elif cfg.routed_scaling_factor != 1.0:
-            # DeepSeek-V2 scales the gates only where it does not renormalise
-            gate_vals = gate_vals * cfg.routed_scaling_factor
-
-    if full_capacity:
-        capacity = s  # a row's expert gets at most one slot per token
-    else:
-        capacity = int(max(1, cfg.capacity_factor * topk * s / e))
-    capacity = min(capacity, s)
+    capacity = min(int(max(1, cfg.capacity_factor * topk * s / e)), s)
 
     def dispatch_row(xr, er):
         """xr: (S, d); er: (S, K) -> per-group expert buffer + slots.
@@ -155,6 +140,81 @@ def moe_apply(p, cfg: ModelConfig, x, *, full_capacity: bool = False
 
     with jax.named_scope("moe.combine"):
         y = jax.vmap(gather_row)(xout, slot, gate_vals, keep)  # (B, S, d)
+    return y, keep
+
+
+def _grouped_expert_ffn(p, x, group_sizes, act: str):
+    """x: (A, d) rows sorted by expert, group_sizes: (Ep,) -> (A, d); each
+    row through its own expert's weights as three grouped products."""
+    def mm(a, w):
+        return grouped_matmul(a, w, group_sizes)
+
+    h = mm(x, p["w1"])
+    if "w3" in p:
+        h = activation("silu" if act == "geglu" else act, h) * mm(x, p["w3"])
+    else:
+        h = activation(act, h)
+    return mm(h, p["w2"])
+
+
+def _sorted_dispatch(x, expert_ids, ep: int):
+    """x: (B, S, d); expert_ids: (B, S, K) -> (rows, inverse, group_sizes).
+
+    rows (B·S·K, d) holds each assignment's token, stably sorted by expert;
+    ``rows[inverse]`` is back in assignment order; group_sizes (Ep,) counts
+    each expert's rows (0 for padded experts).  Gathers keep their
+    ``op_name`` through the TPU compile, so a trace charges them here.
+    """
+    topk = expert_ids.shape[-1]
+    flat = expert_ids.reshape(-1)
+    order = jnp.argsort(flat, stable=True)
+    inverse = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=order.dtype))
+    group_sizes = jnp.bincount(flat, length=ep).astype(jnp.int32)
+    rows = jnp.take(x.reshape(-1, x.shape[-1]), order // topk, axis=0)
+    return rows, inverse, group_sizes
+
+
+def moe_apply(p, cfg: ModelConfig, x, *, full_capacity: bool = False
+              ) -> Tuple[jnp.ndarray, Dict]:
+    """x: (B, S, d) -> (y, metrics).
+
+    full_capacity=True takes the no-drop path: every assignment reaches its
+    expert through the token-sorted grouped dispatch (B·S·K expert rows,
+    however the tokens route).  Inference calls it so, since dropping would
+    break the paper's greedy-equivalence guarantee for blockwise parallel
+    decoding.  False (training) takes the capacity-bounded GShard buffer,
+    batch-sharded over `data` and expert-sharded over `model`, whose
+    data→expert redistribution lowers to an all-to-all on those two dims
+    instead of replicating the token array per expert shard (measured:
+    2.56 TB → GB-scale collectives at prefill_32k; EXPERIMENTS.md §Perf #3).
+    """
+    b, s, d = x.shape
+    e, topk = cfg.num_experts, cfg.num_experts_per_tok
+
+    with jax.named_scope("moe.route"):
+        logits = dense_apply(p["router"], x.astype(jnp.float32))     # (B, S, E)
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate_vals, expert_ids = jax.lax.top_k(probs, topk)           # (B, S, K)
+        if cfg.norm_topk_prob:
+            gate_vals = gate_vals / jnp.maximum(
+                jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)    # renorm
+        elif cfg.routed_scaling_factor != 1.0:
+            # DeepSeek-V2 scales the gates only where it does not renormalise
+            gate_vals = gate_vals * cfg.routed_scaling_factor
+
+    if full_capacity:
+        with jax.named_scope("moe.dispatch"):
+            xs, inverse, group_sizes = _sorted_dispatch(
+                x, expert_ids, cfg.padded_num_experts)
+        with jax.named_scope("moe.experts"):
+            ys = _grouped_expert_ffn(p, xs, group_sizes, cfg.activation)
+        with jax.named_scope("moe.combine"):
+            g = jnp.take(ys, inverse, axis=0).reshape(b, s, topk, d)
+            y = jnp.einsum("bskd,bsk->bsd", g, gate_vals.astype(x.dtype))
+        keep = jnp.ones(expert_ids.shape, bool)
+    else:
+        y, keep = _capacity_dispatch(p, cfg, x, expert_ids, gate_vals)
 
     if "shared" in p:
         with jax.named_scope("moe.shared"):

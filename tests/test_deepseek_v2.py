@@ -448,3 +448,50 @@ def test_gathered_dispatch_bit_identical_when_tokens_drop(arch):
     y0, _ = _parent_moe_apply(p, cfg, x)
     assert float(m["moe_dropped_frac"]) > 0.25
     np.testing.assert_array_equal(np.asarray(y), np.asarray(y0))
+
+
+def _per_row_buffer(p, cfg: ModelConfig, x):
+    """The previous no-drop computation: each batch row's (Ep, S, d) buffer
+    (what ``_parent_moe_apply(full_capacity=True)`` built), through the
+    capacity path at a capacity of S.  That path is the parent's, bit for
+    bit (``test_gathered_dispatch_bit_identical_when_tokens_drop``)."""
+    return moe_apply(p, cfg.replace(capacity_factor=float(cfg.num_experts)),
+                     x)
+
+
+@pytest.mark.parametrize("arch,exact", [("qwen2-moe-a2.7b", False),
+                                        ("olmoe-1b-7b", True),
+                                        ("deepseek-v2-lite", False)])
+def test_sorted_grouped_dispatch_matches_per_row_buffer(arch, exact):
+    """The no-drop path's token-sorted grouped products against the per-row
+    buffer under skewed routing: expert 0 is in every token's top-k,
+    experts 3-5 and the two padded experts get no row, and B·S = 111 (222
+    assignments) divides by no row tile.  olmoe's output is the same bit
+    for bit.  For qwen2-moe and DeepSeek the CPU's product over a 128-row
+    tile sums in another order than its product over a row's 37-slot
+    buffer: within 1e-6 of the output's scale, as close to a float64
+    reference as the per-row buffer is."""
+    cfg = get_config(arch, smoke=True).replace(
+        dtype="float32", num_experts=6, expert_pad_multiple=4)
+    assert cfg.padded_num_experts == 8
+    p = moe_init(jax.random.PRNGKey(1), cfg)
+    d = cfg.d_model
+    u = jnp.ones((d,), jnp.float32) / math.sqrt(d)
+    w = p["router"]["w"] * 0.1
+    w = w.at[:, 0].set(10.0 * u).at[:, 3:].set(-10.0 * u[:, None])
+    p["router"]["w"] = w
+    x = jax.random.normal(jax.random.PRNGKey(2), (3, 37, d)) + 5.0 * u
+    ids = np.asarray(jax.lax.top_k(x @ w, cfg.num_experts_per_tok)[1])
+    assert (ids == 0).any(-1).all() and not (ids >= 3).any()
+
+    got, m = jax.jit(lambda p, x: moe_apply(p, cfg, x,
+                                            full_capacity=True))(p, x)
+    want, m0 = jax.jit(lambda p, x: _per_row_buffer(p, cfg, x))(p, x)
+    assert float(m["moe_dropped_frac"]) == 0.0
+    assert abs(float(m0["moe_dropped_frac"])) < 1e-6
+    got, want = np.asarray(got), np.asarray(want)
+    if exact:
+        np.testing.assert_array_equal(got, want)
+    else:
+        err = np.abs(got - want).max() / np.abs(want).max()
+        assert err <= 1e-6, err

@@ -159,3 +159,32 @@ def test_moe_layer_ops_keep_their_scopes(spec):
         params, spec((32, 8, cfg.d_model), jnp.bfloat16)).compile().as_text()
     assert "moe.dispatch" in hlo and "moe.experts" in hlo
     assert _unscoped_large_ops(hlo, "moe.", 4 << 20) == []
+
+
+@pytest.mark.parametrize("shape", [(32, 8), (1, 512)],
+                         ids=["verify", "admission"])
+def test_moe_no_drop_layer_computes_only_routed_rows(spec, shape):
+    """A DeepSeek-V2-Lite MoE layer on the no-drop path, compiled for the
+    v5e at the benchmark's verify-step shape (32 rows of 8) and a
+    512-token admission: its FLOPs stay within 1.5× of the work it needs
+    (B·S·6 routed expert rows, the router and the shared experts).  A
+    full-capacity buffer (B·Ep·S rows) or a masked dense product over all
+    64 experts counts 8× or more."""
+    cfg = get_config("deepseek-v2-lite")
+    params = jax.tree.map(
+        lambda a: spec(a.shape, a.dtype),
+        jax.eval_shape(lambda k: moe.moe_init(k, cfg, dtype=jnp.bfloat16),
+                       jax.random.PRNGKey(0)))
+    tokens = math.prod(shape)
+    d, ff, sff = cfg.d_model, cfg.d_ff, cfg.shared_expert_d_ff
+    needed = 2 * tokens * d * (cfg.num_experts_per_tok * 3 * ff
+                               + cfg.num_experts + 3 * sff)
+
+    def layer(p, x):
+        return moe.moe_apply(p, cfg, x, full_capacity=True)[0]
+
+    compiled = jax.jit(layer).lower(
+        params, spec(shape + (d,), jnp.bfloat16)).compile()
+    cost = compiled.cost_analysis()
+    flops = (cost[0] if isinstance(cost, list) else cost)["flops"]
+    assert needed <= flops <= 1.5 * needed, (flops, needed)
