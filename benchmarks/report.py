@@ -189,37 +189,6 @@ def kernels_md(bench) -> str:
     return "\n".join(out)
 
 
-def serve_slo_md(bench) -> str:
-    """Serving SLO rows from BENCH_serve.json (the ``slo_*`` keys written
-    by benchmarks/slo_harness.py through the real HTTP/SSE server)."""
-    if not bench or "slo_poisson" not in bench:
-        return ("_no slo_* rows in BENCH_serve.json — run "
-                "`python benchmarks/slo_harness.py --smoke`_")
-    traces = [(k[len("slo_"):], bench[k]) for k in
-              ("slo_poisson", "slo_bursty", "slo_preempt", "slo_paged")
-              if k in bench]
-    out = ["| trace | req | TTFT p50/p99 (ms) | TPOT p50/p99 (ms) | "
-           "tok/s | preempt | 429 (rate) | pool requeues |",
-           "|---|---|---|---|---|---|---|---|"]
-    ms = lambda v: f"{float(v) * 1e3:.1f}"  # noqa: E731
-    for name, t in traces:
-        out.append(
-            f"| {name} | {t['requests']} | "
-            f"{ms(t['ttft_p50_s'])} / {ms(t['ttft_p99_s'])} | "
-            f"{ms(t['tpot_p50_s'])} / {ms(t['tpot_p99_s'])} | "
-            f"{t['tokens_per_sec']:.0f} | {t['preemptions']} | "
-            f"{t['rejected_429']} ({t['rejected_429_rate']:.2f}) | "
-            f"{t['backpressure_requeues']} |")
-    out.append("")
-    out.append(f"Measured through the real HTTP/SSE server (TTFT from the "
-               f"first send attempt, so 429 retries count against it).  "
-               f"Quality gate: streamed tokens identical to an in-process "
-               f"engine run over {bench.get('slo_quality_compared', '?')} "
-               f"requests — including the preempted, pool-requeued, and "
-               f"429-retried ones.")
-    return "\n".join(out)
-
-
 def dryrun_md(recs) -> str:
     if not recs:
         return "_no dry-run records yet_"
@@ -313,15 +282,12 @@ def main():
     t2 = _load("experiments/table2.json")
     t4 = _load("experiments/table4.json")
     bench = _load("BENCH_decode.json")
-    serve = _load("BENCH_serve.json")
     recs = load_records("experiments/dryrun")
 
     if not os.path.exists(EXP):
-        print(f"[report] {EXP} not present — printing the KERNELS and "
-              f"SERVE sections instead of patching markers")
+        print(f"[report] {EXP} not present — printing the KERNELS "
+              f"section instead of patching markers")
         print(kernels_md(bench))
-        print()
-        print(serve_slo_md(serve))
         return
     with open(EXP) as f:
         text = f.read()
@@ -331,7 +297,6 @@ def main():
         ("TABLE4", table4_md(t4)),
         ("CLAIMS", claims_md(t1, t2, t4)),
         ("KERNELS", kernels_md(bench)),
-        ("SERVE", serve_slo_md(serve)),
         ("DRYRUN", dryrun_md(recs)),
         ("ROOFLINE", roofline_md(recs)),
     ):

@@ -82,11 +82,6 @@ def load_records(dryrun_dir: str):
     for path in sorted(glob.glob(os.path.join(dryrun_dir, "*.json"))):
         with open(path) as f:
             r = json.load(f)
-        if r.get("kind", "roofline") != "roofline":
-            # `dryrun.py --handoff` drops KV-handoff/donation records in the
-            # same directory; they carry collective byte counts, not a
-            # per-step cost analysis, so there is nothing to roofline.
-            continue
         recs.append(recompute_terms(r))
     return recs
 

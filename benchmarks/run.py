@@ -13,9 +13,9 @@ under experiments/.
 ``--quick`` runs reduced step counts (CI-sized); default is the full
 CPU-scale reproduction (~30-45 min).  ``--smoke`` runs only the
 seconds-scale subset (kernels + roofline + policies) — the CI
-benchmark-smoke job pairs it with ``benchmarks/serve_throughput.py
---smoke`` and FAILS if the ``exact`` policy's mean-k̂ regresses against
-the committed ``BENCH_decode.json`` baseline, if no new drafter beats
+benchmark-smoke job runs it, and it FAILS if the ``exact`` policy's
+mean-k̂ regresses against the committed ``BENCH_decode.json`` baseline,
+if no new drafter beats
 HeadsDrafter+exact, if the distilled ``draft_model`` drafter stops
 beating heads+exact, if the ``adaptive`` rows collapse back to
 metric-identical-with-exact (cap never binding), if the scheduled-
@@ -325,10 +325,10 @@ def main():
         # against `_ref` or compiled rows.)
 
     # repo-root perf-trajectory artifact (committed, so the smoke numbers
-    # are diffable PR over PR; serve_throughput.py writes BENCH_serve.json).
-    # Only the FULL smoke configuration writes it — a partial `--smoke
-    # --only kernels` run would drop the policies rows and silently disarm
-    # the regression gate for every later run against the committed file.
+    # are diffable PR over PR).  Only the FULL smoke configuration writes
+    # it — a partial `--smoke --only kernels` run would drop the policies
+    # rows and silently disarm the regression gate for every later run
+    # against the committed file.
     if args.smoke and which == {"kernels", "roofline", "policies"}:
         with open(bench_path, "w") as f:
             json.dump({"smoke": True, "which": sorted(which), "rows": rows},

@@ -16,7 +16,6 @@ Usage:
 import argparse
 import json
 import os
-import re
 import time
 import traceback
 from typing import Dict, Optional
@@ -251,114 +250,6 @@ def run_combo(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
     return rec
 
 
-def run_handoff(arch: str, out_dir: str, *, verbose: bool = True) -> Dict:
-    """Lower the disaggregated prefill→decode KV handoff on the multi-pod
-    ``("pod","data","model")`` mesh and measure it.
-
-    Two numbers the serving design stands on:
-
-      * the **handoff transfer** — ``attach`` moves one prefill-packet row
-        (pod-axis sharded, ``sharding.policy.packet_specs``) into the
-        pod×data-sharded slot slab; the sharding-constrained lowering's
-        collective bytes ARE that device-to-device transfer;
-      * the **donate_argnums HBM claim** — the slot state is donated, so
-        attach/step must alias their output state onto the input buffers
-        instead of double-buffering the KV slab.  Verified from the
-        compiled ``input_output_alias`` table with a before/after buffer
-        accounting row (donated vs. no-donation lowering of the SAME
-        attach).
-    """
-    from repro.serving.session import DecodeSession
-    from repro.serving.types import EngineConfig
-
-    tag = f"{arch}_handoff_pod2x16x16"
-    cfg = get_config(arch, smoke=True).replace(dtype="float32")
-    dec = DecodeConfig(max_new_tokens=32, block_k=cfg.bpd_k or 4)
-    mesh = make_production_mesh(multi_pod=True)
-    pod, data = mesh.shape["pod"], mesh.shape["data"]
-    # slot slab shards pod×data; prefill width shards the pod axis alone
-    ecfg = EngineConfig(num_slots=pod * data, max_prompt_len=32,
-                        max_new_cap=32, prefill_slots=2 * pod)
-    params = model_lib.init(jax.random.PRNGKey(0), cfg)
-
-    def lower_pair(donate: bool):
-        with jax.set_mesh(mesh):
-            sess = DecodeSession(params, cfg, dec, mesh=mesh, donate=donate)
-            fns = sess.serving_fns(ecfg)
-            state = jax.eval_shape(fns.init, jnp.zeros((), jnp.int32))
-            w = ecfg.prefill_slots
-            prompts = jax.ShapeDtypeStruct((w, ecfg.max_prompt_len), jnp.int32)
-            plens = jax.ShapeDtypeStruct((w,), jnp.int32)
-            pkt = jax.eval_shape(fns.prefill, sess.params, sess.aux_params,
-                                 prompts, plens, prompts)
-            scalar = jax.ShapeDtypeStruct((), jnp.int32)
-            jit_of = lambda f: getattr(f, "_jitted", f)  # noqa: E731
-            pre = jit_of(fns.prefill).lower(
-                sess.params, sess.aux_params, prompts, plens,
-                prompts).compile()
-            att = jit_of(fns.attach).lower(
-                state, pkt, scalar, scalar, scalar).compile()
-        return pre, att, state
-
-    t0 = time.time()
-    pre, att, state = lower_pair(donate=True)
-    _, att_nodon, _ = lower_pair(donate=False)
-    t_compile = time.time() - t0
-
-    att_hlo = att.as_text()
-    # the compiled alias table is the proof of donation: every aliased
-    # (output, input-param) pair reuses the input buffer in place.  Each
-    # table entry ends in "must-alias)" / "may-alias)".
-    alias_pairs = (len(re.findall(r"(?:must|may)-alias\)", att_hlo))
-                   if "input_output_alias" in att_hlo else 0)
-    state_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize
-                      for x in jax.tree_util.tree_leaves(state))
-
-    def _sizes(compiled):
-        m = compiled.memory_analysis()
-        get = lambda n: int(getattr(m, n, 0) or 0)  # noqa: E731
-        return {"argument_size_bytes": get("argument_size_in_bytes"),
-                "output_size_bytes": get("output_size_in_bytes"),
-                "temp_size_bytes": get("temp_size_in_bytes"),
-                "alias_size_bytes": get("alias_size_in_bytes")}
-
-    don, nodon = _sizes(att), _sizes(att_nodon)
-    # peak live bytes for one attach = args + outputs + temps − aliased
-    # (aliased outputs reuse argument buffers); the donation saving is the
-    # drop in that total between the two lowerings of the SAME function
-    peak = lambda s: (s["argument_size_bytes"] + s["output_size_bytes"]  # noqa: E731
-                      + s["temp_size_bytes"] - s["alias_size_bytes"])
-    rec = {
-        "arch": arch, "mesh": "pod2x16x16", "status": "ok",
-        "kind": "handoff",
-        "chips": int(np.prod(mesh.devices.shape)),
-        "prefill_slots": ecfg.prefill_slots, "num_slots": ecfg.num_slots,
-        "compile_s": round(t_compile, 2),
-        "prefill_collectives": collective_bytes(pre.as_text()),
-        "handoff_collectives": collective_bytes(att_hlo),
-        "donate": {
-            "state_bytes_global": state_bytes,
-            "alias_pairs_in_hlo": alias_pairs,
-            "with_donation": don,
-            "without_donation": nodon,
-            "peak_live_bytes_with": peak(don),
-            "peak_live_bytes_without": peak(nodon),
-            "hbm_saving_bytes": peak(nodon) - peak(don),
-        },
-    }
-    _write(out_dir, tag, rec)
-    if verbose:
-        d = rec["donate"]
-        print(f"[dryrun] {tag}: OK handoff_coll="
-              f"{rec['handoff_collectives']['total_bytes']:.3e}B "
-              f"alias_pairs={d['alias_pairs_in_hlo']} "
-              f"state={d['state_bytes_global']:.3e}B "
-              f"peak live {d['peak_live_bytes_without']:.3e}B -> "
-              f"{d['peak_live_bytes_with']:.3e}B "
-              f"(saves {d['hbm_saving_bytes']:.3e}B)")
-    return rec
-
-
 def _write(out_dir: str, tag: str, rec: Dict) -> None:
     os.makedirs(out_dir, exist_ok=True)
     with open(os.path.join(out_dir, tag + ".json"), "w") as f:
@@ -384,16 +275,7 @@ def main() -> None:
                     help="lower inference kinds with bf16 params (§Perf #2)")
     ap.add_argument("--remat", action="store_true",
                     help="per-block activation checkpointing for train (§Perf #4)")
-    ap.add_argument("--handoff", action="store_true",
-                    help="lower the disaggregated prefill→decode KV handoff "
-                         "(attach) on the multi-pod mesh: measures the "
-                         "device-to-device transfer bytes and verifies the "
-                         "donate_argnums HBM claim (smoke config)")
     args = ap.parse_args()
-
-    if args.handoff:
-        run_handoff(args.arch or "granite-3-8b", args.out)
-        return
 
     from repro.configs import ASSIGNED
 

@@ -37,8 +37,8 @@ def make_host_mesh(data: int = 1, model: int = 1, *, pod: int = 1,
     """Small host mesh over whatever devices exist (tests / local runs):
     ("data", "model"), or the production ("pod", "data", "model") shape
     when ``pod > 1`` — the host-scale twin of ``make_production_mesh``'s
-    multi-pod layout (prefill workers shard over the pod axis, the slot
-    slab over pod×data; see sharding.policy).  Axes shrink to fit the
+    multi-pod layout (the slot slab shards over pod×data; see
+    sharding.policy).  Axes shrink to fit the
     available device count unless ``require=True`` — then an
     under-provisioned host raises instead of silently degrading a sharded
     run to fewer shards (use

@@ -153,17 +153,8 @@ def main():
                     help="tensor-parallel shards over the model axis")
     ap.add_argument("--mesh-pod", type=int, default=1,
                     help="pod-parallel shards (production "
-                         "('pod','data','model') mesh shape; prefill "
-                         "workers shard over the pod axis, the slot slab "
-                         "over pod×data)")
-    ap.add_argument("--prefill-slots", type=int, default=0,
-                    help="disaggregated prefill/decode: prompts prefilled "
-                         "per prefill-worker forward, handed to decode "
-                         "groups through the bounded KV-handoff queue "
-                         "(0 = unified engine, admission prefills inline)")
-    ap.add_argument("--handoff-cap", type=int, default=0,
-                    help="bound on requests staged for / parked in the "
-                         "KV-handoff queue (0 = auto)")
+                         "('pod','data','model') mesh shape; the slot slab "
+                         "shards over pod×data)")
     args = ap.parse_args()
 
     enable_compile_cache()
@@ -363,9 +354,7 @@ def serve_http(params, cfg, dec, args, *, mesh=None, bundles=None,
 
     ecfg = EngineConfig(num_slots=args.batch,
                         max_prompt_len=args.prompt_len,
-                        max_new_cap=args.max_new,
-                        prefill_slots=args.prefill_slots,
-                        handoff_cap=args.handoff_cap)
+                        max_new_cap=args.max_new)
     engine = ContinuousBatchingEngine(params, cfg, dec, ecfg, mesh=mesh,
                                       bundles=bundles, policies=groups)
     sched = Scheduler(engine, policy=args.sched)
@@ -385,12 +374,10 @@ def serve_http(params, cfg, dec, args, *, mesh=None, bundles=None,
             loop.add_signal_handler(signal.SIGINT, srv.begin_drain)
         except NotImplementedError:    # non-Unix event loops
             pass
-        mode = (f"disaggregated prefill_slots={args.prefill_slots}"
-                if args.prefill_slots else "unified")
         print(f"[serve] http on {srv.host}:{srv.port} — POST /v1/generate "
               f"/drain, GET /healthz /readyz /metrics "
               f"(slots={args.batch}, sched={args.sched}, "
-              f"max_queue={args.max_queue}, {mode})", flush=True)
+              f"max_queue={args.max_queue})", flush=True)
         if args.http_demo:
             await _http_demo(srv)
             await srv.stop()

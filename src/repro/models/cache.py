@@ -285,8 +285,8 @@ class KVCacheBackend:
     def row_init(self, cfg: ModelConfig, context_len: int, block_k: int,
                  dtype=None, *, batch: int = 1):
         """Admission-prefill workspace: ``batch`` rows in the dense row
-        layout (batch > 1 = a prefill worker's whole packet at once; each
-        row is still scattered into a slot individually)."""
+        layout (batch > 1 = a whole prefill packet at once; each row is
+        still scattered into a slot individually)."""
         from repro.models import model as model_lib
 
         return model_lib.init_caches(cfg, batch, context_len, block_k, dtype,

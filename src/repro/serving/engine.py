@@ -55,9 +55,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from collections import deque
-from typing import (Any, Deque, Dict, List, Optional, Sequence, Tuple,
-                    Union)
+from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -71,7 +69,7 @@ from repro.serving.types import (EngineConfig, FinishedRequest,
                                  PreemptedRequest, Request, SlotBatch)
 
 __all__ = ["ContinuousBatchingEngine", "PolicyGroup", "SlotBatch",
-           "PagePoolExhausted", "PreemptedRequest", "HandoffRecord"]
+           "PagePoolExhausted", "PreemptedRequest"]
 
 I32 = jnp.int32
 
@@ -98,21 +96,6 @@ class PolicyGroup:
         the one definition of "free" shared by admission and the engine's
         global free-slot view."""
         return [i for i in range(self.num_slots) if not self.status[i] & 1]
-
-
-@dataclasses.dataclass
-class HandoffRecord:
-    """One finished prefill parked in the KV-handoff queue: row ``row`` of
-    the device-side ``packet`` (a ``session.PrefillPacket``, shared by up
-    to ``prefill_slots`` records from the same worker batch) plus the host
-    metadata ``attach`` needs to install it into a freed slot."""
-
-    req: Request
-    packet: Any                 # device PrefillPacket (shared per batch)
-    row: int                    # this request's row inside the packet
-    prompt_len: int
-    max_new: int
-    prefill_time: float         # when the prefill batch was dispatched
 
 
 def _normalize_groups(policies, default_name: str,
@@ -225,27 +208,12 @@ class ContinuousBatchingEngine:
         self._by_name = {g.name: g for g in self.groups}
         self._rr = 0            # round-robin pointer over group steps
 
-        # -- disaggregated prefill/decode (prefill_slots > 0): dedicated
-        # prefill workers batch prompt prefills and park the finished KV
-        # state in a bounded handoff queue; decode groups pull rows into
-        # freed slots without ever serializing admission behind a step ----
-        self.disaggregated = ecfg.prefill_slots > 0
-        self.prefill_width = max(ecfg.prefill_slots, 1)
-        self.handoff_cap = ecfg.handoff_cap or max(2 * ecfg.num_slots,
-                                                   ecfg.prefill_slots)
-        self._staged: Dict[str, List[Tuple[Request, float]]] = {
-            g.name: [] for g in self.groups}         # awaiting a prefill
-        self._handoff: Dict[str, Deque[HandoffRecord]] = {
-            g.name: deque() for g in self.groups}    # awaiting a slot
-
-        self.num_admits = 0     # requests entering a slot (admit or attach)
+        self.num_admits = 0     # requests admitted into a slot
         self.num_steps = 0      # decode ITERATIONS (full-width model
                                 # forwards; a windowed step adds every
                                 # iteration its while_loop actually ran)
         self.num_host_syncs = 0  # device->host readbacks (regression guard)
         self.num_stream_syncs = 0  # poll_progress readbacks (streaming only)
-        self.num_prefill_batches = 0   # prefill-worker forwards dispatched
-        self.num_attach_backpressure = 0  # attach stalls (page pool full)
         # host time per phase is in the ``serve.engine.*`` spans
         # (serving/tracing.py); ``_gap_from`` is when the last status pull
         # returned, while a device program is still to be dispatched after
@@ -314,8 +282,7 @@ class ContinuousBatchingEngine:
         return any(bool(np.any(g.status & 1)) for g in self.groups)
 
     def _padded(self, req: Request) -> Tuple[np.ndarray, int, np.ndarray, int]:
-        """Pad a request's prompt/src rows to the admission geometry (the
-        one definition shared by unified admit and the prefill workers)."""
+        """Pad a request's prompt/src rows to the admission geometry."""
         p = len(req.prompt)
         if not 0 < p <= self.ecfg.max_prompt_len:
             raise ValueError(
@@ -371,170 +338,6 @@ class ContinuousBatchingEngine:
             "admit_time": admit_time, "emitted": 0,
         }
         return g.offset + slot
-
-    # -- disaggregated prefill/decode ----------------------------------------
-
-    def handoff_backlog(self) -> int:
-        """Requests staged for prefill plus rows parked in the KV-handoff
-        queue — work admitted to the engine that holds no slot yet."""
-        return (sum(len(v) for v in self._staged.values())
-                + sum(len(v) for v in self._handoff.values()))
-
-    def handoff_free(self) -> int:
-        """Remaining capacity of the bounded handoff pipeline (staged +
-        parked share one bound so prefill output can never pile up
-        unboundedly when decode stalls)."""
-        return self.handoff_cap - self.handoff_backlog()
-
-    def queue_prefill(self, req: Request, *, now: Optional[float] = None) -> None:
-        """Stage a request for the prefill workers (disaggregated mode
-        only).  Validates geometry now so malformed requests fail at
-        submission, not inside a worker batch; raises RuntimeError when the
-        handoff pipeline is full (back-pressure — callers check
-        ``handoff_free()`` first, exactly like ``free_slots`` for admit)."""
-        if not self.disaggregated:
-            raise RuntimeError(
-                "queue_prefill requires a disaggregated engine "
-                "(EngineConfig.prefill_slots > 0); unified engines admit "
-                "directly")
-        g = self.group_for(req.policy)
-        self._padded(req)           # geometry validation only
-        if self.handoff_free() <= 0:
-            raise RuntimeError(
-                f"KV-handoff queue full ({self.handoff_cap} staged+parked) "
-                f"— poll attach_ready()/step() first")
-        t = time.monotonic() if now is None else now
-        if req.arrival is None:
-            req.arrival = t
-        self._staged[g.name].append((req, t))
-
-    def run_prefills(self, *, now: Optional[float] = None) -> int:
-        """Dispatch prefill-worker batches for everything staged: each
-        batch prefills up to ``prefill_slots`` prompts in ONE forward
-        (short batches are padded with inert dummy rows — same static
-        shape, so the worker compiles once) and parks its rows in the
-        handoff queue as ``HandoffRecord``s sharing the device packet.
-        Dispatch-only — no device→host sync.  Returns rows parked."""
-        with tracing.span("serve.engine.prefill"):
-            return self._run_prefills(now)
-
-    def _run_prefills(self, now: Optional[float]) -> int:
-        parked = 0
-        w = self.prefill_width
-        for g in self.groups:
-            staged = self._staged[g.name]
-            while staged:
-                if (len(staged) < w
-                        and (self._handoff[g.name]
-                             or not g.free_local())):
-                    # coalesce: parked rows already cover the free slots
-                    # (or none are free), so a partial batch buys no TTFT
-                    # — hold the stage until a full-width batch forms.
-                    # The moment a slot opens with nothing parked, the
-                    # next call dispatches whatever is staged: deferring
-                    # past that point idles decode slots, which costs
-                    # more than the padded partial forward saves
-                    break
-                batch, self._staged[g.name] = staged[:w], staged[w:]
-                staged = self._staged[g.name]
-                prompts = np.zeros((w, self.ecfg.max_prompt_len), np.int32)
-                plens = np.ones((w,), np.int32)   # dummy rows: 1-token prompt
-                srcs = np.zeros((w, self.ecfg.max_prompt_len), np.int32)
-                rows = []
-                for r, (req, _) in enumerate(batch):
-                    prompt, p, src, max_new = self._padded(req)
-                    prompts[r], plens[r], srcs[r] = prompt, p, src
-                    rows.append((req, r, p, max_new))
-                packet = g.fns.prefill(self.params, self.aux_params,
-                                       jnp.asarray(prompts),
-                                       jnp.asarray(plens), jnp.asarray(srcs))
-                self._dispatched()
-                self.num_prefill_batches += 1
-                t = time.monotonic() if now is None else now
-                for req, r, p, max_new in rows:
-                    self._handoff[g.name].append(HandoffRecord(
-                        req=req, packet=packet, row=r, prompt_len=p,
-                        max_new=max_new, prefill_time=t))
-                    parked += 1
-        return parked
-
-    def attach_ready(self, *, now: Optional[float] = None) -> int:
-        """Install parked handoff rows into freed decode slots (the
-        prefill→decode KV handoff — under a pod mesh this is the
-        sharding-constrained device-to-device transfer).  FIFO per group;
-        a page-pool-exhausted head waits in place (head-of-line, so
-        admission order within a group is preserved).
-
-        Consecutive records sharing one prefill packet install in ONE
-        ``attach_many`` dispatch (a per-record attach call would hand
-        back the dispatch overhead that batching the prefill amortized).
-        Returns the number of requests attached."""
-        with tracing.span("serve.engine.attach"):
-            return self._attach_ready(now)
-
-    def _attach_ready(self, now: Optional[float]) -> int:
-        attached = 0
-        w = self.prefill_width
-        for g in self.groups:
-            q = self._handoff[g.name]
-            while q:
-                free = g.free_local()
-                if not free:
-                    break
-                # gather up to W head records from the SAME packet that
-                # have both a free slot and (if paged) a page plan
-                pkt = q[0].packet
-                batch, blocked = [], False
-                while (q and q[0].packet is pkt and len(batch) < len(free)
-                       and len(batch) < w):
-                    rec, slot = q[0], free[len(batch)]
-                    extra = None
-                    if g.pages is not None:
-                        try:
-                            extra = g.pages.plan_admit(
-                                slot, rec.req.prompt, rec.prompt_len,
-                                rec.max_new, self.block_k)
-                        except PagePoolExhausted:
-                            # head-of-line: the failed record waits for a
-                            # release; whatever fit still attaches below
-                            self.num_attach_backpressure += 1
-                            blocked = True
-                            break
-                    q.popleft()
-                    batch.append((rec, slot, extra))
-                if not batch:
-                    break
-                rows = np.zeros((w,), np.int32)
-                slots = np.zeros((w,), np.int32)
-                maxn = np.zeros((w,), np.int32)
-                valid = np.zeros((w,), bool)
-                for i, (rec, slot, _) in enumerate(batch):
-                    rows[i], slots[i] = rec.row, slot
-                    maxn[i], valid[i] = rec.max_new, True
-                pextra = ()
-                if g.pages is not None:
-                    P_ = g.fns.paged.pages_per_row
-                    tbls = np.zeros((w, P_), np.int32)
-                    masks = np.zeros((w, P_), bool)
-                    for i, (_, _, (tbl_row, write_mask)) in enumerate(batch):
-                        tbls[i], masks[i] = tbl_row, write_mask
-                    pextra = (jnp.asarray(tbls), jnp.asarray(masks))
-                g.state = g.fns.attach_many(
-                    g.state, pkt, jnp.asarray(rows), jnp.asarray(slots),
-                    jnp.asarray(maxn), jnp.asarray(valid), *pextra)
-                self._dispatched()
-                t = time.monotonic() if now is None else now
-                for rec, slot, _ in batch:
-                    g.status[slot] = 1  # known host-side: no readback needed
-                    self.num_admits += 1
-                    g.slot_meta[slot] = {
-                        "req": rec.req, "prompt_len": rec.prompt_len,
-                        "max_new": rec.max_new, "admit_time": t, "emitted": 0,
-                    }
-                attached += len(batch)
-                if blocked:
-                    break
-        return attached
 
     def step(self, *, now: Optional[float] = None) -> List[FinishedRequest]:
         """One BPD iteration over every active slot group, then
@@ -752,15 +555,11 @@ class ContinuousBatchingEngine:
             if id(g.fns) in seen:
                 continue
             seen.add(id(g.fns))
-            for part in ("admit", "prefill", "attach", "attach_many",
-                         "step", "evict"):
+            for part in ("admit", "step", "evict"):
                 n = getattr(g.fns, part)._cache_size()
                 if n == 0:
-                    # never traced — unified engines don't call the
-                    # prefill/attach pair, disaggregated ones only reach
-                    # admit through preemption; an uncalled fn can't have
-                    # recompiled, so a 0 would only trip the strict ==1
-                    # gates for paths a run legitimately never took
+                    # a group that never ran this function (no request
+                    # reached it) has nothing that could have recompiled
                     continue
                 key = part if single else f"{g.name}/{part}"
                 out[key] = n

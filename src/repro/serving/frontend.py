@@ -129,9 +129,9 @@ class Frontend:
     async def drain(self) -> None:
         """Graceful drain: stop admission immediately (``submit`` raises
         ``Draining``; readiness goes false so load balancers route away),
-        let every in-flight request — queued, parked in the KV handoff,
-        or mid-decode — run to completion with its SSE tail flushed
-        through the normal stream path, then stop the serve loop.
+        let every in-flight request — queued or mid-decode — run to
+        completion with its SSE tail flushed through the normal stream
+        path, then stop the serve loop.
 
         Idempotent and safe to call concurrently with traffic: the serve
         loop itself detects quiescence (between ticks, so it never races
@@ -311,11 +311,7 @@ class Frontend:
             "stream_syncs_total": eng.num_stream_syncs,
             "tpot_estimate_seconds": sch.tpot_est,
             "draining": int(self._draining),
-            # disaggregated prefill/decode + async-stream attribution
-            "disaggregated": int(eng.disaggregated),
-            "prefill_batches_total": eng.num_prefill_batches,
-            "handoff_backlog": eng.handoff_backlog(),
-            "attach_backpressure_total": eng.num_attach_backpressure,
+            # harvests that overlapped another group's device step
             "overlap_harvests_total": eng.num_overlap_harvests,
             **tracing.snapshot(),
         }

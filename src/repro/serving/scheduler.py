@@ -44,7 +44,7 @@ influences *which* tokens commit are the documented exception).
 
 ``run()`` drives a whole workload to completion on a real clock: requests
 with future arrival times are invisible until the clock reaches them
-(Poisson open-loop traffic in benchmarks/serve_throughput.py).  The async
+(open-loop traffic).  The async
 HTTP front end (``serving.frontend``) drives ``step()`` itself and drains
 ``take_preempt_events()`` for stream bookkeeping.
 """
@@ -113,16 +113,12 @@ class Scheduler:
                 else (r.arrival, r.rid))
         return (-r.priority, 0 if r.backpressured else 1) + base
 
-    def _pop_next(self, now: float,
-                  group: Optional[str] = None) -> Optional[Request]:
-        """Best eligible request — optionally only those routed to the
-        ``group`` policy slot group."""
-        eligible = [r for r in self.queue if r.arrival <= now]
-        if group is not None:
-            # delegate routing to the engine — one source of truth for
-            # which group a request's policy lands in
-            eligible = [r for r in eligible
-                        if self.engine.group_for(r.policy).name == group]
+    def _pop_next(self, now: float, group: str) -> Optional[Request]:
+        """Best eligible request routed to the ``group`` policy slot group
+        (the engine decides the routing: one source of truth for which
+        group a request's policy lands in)."""
+        eligible = [r for r in self.queue if r.arrival <= now
+                    and self.engine.group_for(r.policy).name == group]
         if not eligible:
             return None
         pick = min(eligible, key=self._key)
@@ -253,44 +249,25 @@ class Scheduler:
 
     def _step(self, now: Optional[float]) -> List[FinishedRequest]:
         t = time.monotonic() if now is None else now
-        # preemption runs BEFORE admission/staging: a deadline-at-risk
-        # request must claim its slot while it is still in the queue — the
-        # disaggregated staging loop below would otherwise move it into
-        # the handoff queue, where it waits behind the very decode it was
-        # entitled to evict (groups with free slots are skipped, so this
-        # never steals an admission a natural free slot would satisfy)
+        # preemption runs before admission (groups with free slots are
+        # skipped, so it never steals an admission a free slot would serve)
         self._maybe_preempt(t)
-        if self.engine.disaggregated:
-            # disaggregated admission: stage arrivals for the prefill
-            # workers while handoff capacity lasts — admission never waits
-            # for (or serializes behind) a decode slot — then dispatch the
-            # worker batches and install parked rows into freed slots.
-            # Page-pool back-pressure is handled at attach inside the
-            # engine (head-of-line wait in the handoff queue).
-            while self.engine.handoff_free() > 0:
-                req = self._pop_next(t)
+        for name in self.engine.policy_names():
+            for _ in range(len(self.engine.free_slots(name))):
+                req = self._pop_next(t, group=name)
                 if req is None:
                     break
-                self.engine.queue_prefill(req, now=now)
-            self.engine.run_prefills(now=now)
-            self.engine.attach_ready(now=now)
-        else:
-            for name in self.engine.policy_names():
-                for _ in range(len(self.engine.free_slots(name))):
-                    req = self._pop_next(t, group=name)
-                    if req is None:
-                        break
-                    try:
-                        self.engine.admit(req, now=now)
-                    except PagePoolExhausted:
-                        # back-pressure: the paged KV pool can oversubscribe
-                        # the slot slab — requeue with head-of-line ownership
-                        # and stop admitting to this group until decode steps
-                        # retire requests and free pages
-                        req.backpressured += 1
-                        self.backpressure_events += 1
-                        self.queue.append(req)
-                        break
+                try:
+                    self.engine.admit(req, now=now)
+                except PagePoolExhausted:
+                    # back-pressure: the paged KV pool can oversubscribe
+                    # the slot slab — requeue with head-of-line ownership
+                    # and stop admitting to this group until decode steps
+                    # retire requests and free pages
+                    req.backpressured += 1
+                    self.backpressure_events += 1
+                    self.queue.append(req)
+                    break
         if not self.engine.has_active():
             return []
         done = [self._stitch(f) for f in self.engine.step(now=now)]
@@ -303,8 +280,7 @@ class Scheduler:
         return done
 
     def drained(self) -> bool:
-        return (not self.queue and not self.engine.has_active()
-                and self.engine.handoff_backlog() == 0)
+        return not self.queue and not self.engine.has_active()
 
     def run(self, max_steps: int = 100_000) -> List[FinishedRequest]:
         """Drive until every submitted request has been served."""
@@ -314,8 +290,7 @@ class Scheduler:
                 raise RuntimeError(f"scheduler did not drain in {max_steps} "
                                    f"steps ({len(self.queue)} queued)")
             now = time.monotonic()
-            if (not self.engine.has_active() and not self.pending(now)
-                    and self.engine.handoff_backlog() == 0):
+            if not self.engine.has_active() and not self.pending(now):
                 # idle: sleep until the next arrival (drained() was false
                 # with nothing in flight, so the queue is non-empty)
                 nxt = min(r.arrival for r in self.queue)

@@ -142,10 +142,9 @@ class HTTPServer:
     def begin_drain(self) -> asyncio.Task:
         """Start a graceful drain exactly once (idempotent): admission
         stops immediately (new submits get 503), every in-flight request
-        — queued, parked in the KV handoff, or mid-decode — finishes and
-        flushes its SSE tail, then the listener closes so
-        ``serve_forever()`` returns.  Wired to SIGTERM and ``POST /drain``
-        by ``launch/serve.py``."""
+        — queued or mid-decode — finishes and flushes its SSE tail, then
+        the listener closes so ``serve_forever()`` returns.  Wired to
+        SIGTERM and ``POST /drain`` by ``launch/serve.py``."""
         if self._drain_task is None:
             self._drain_task = asyncio.ensure_future(self._drain_and_close())
         return self._drain_task

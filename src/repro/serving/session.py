@@ -76,18 +76,13 @@ def _geometry(batch: Dict) -> tuple:
 
 class PrefillPacket(NamedTuple):
     """Finished prefill state for a batch of prompts, before any slot is
-    chosen — the unit of work a prefill worker hands to a decode group
-    through the engine's KV-handoff queue.
+    chosen.
 
     Every leaf leads with the prefill width ``W``; row ``i`` is one
     request's complete admission state (token buffer, first-block
-    proposals, prefilled KV caches, fresh per-row policy state).  A packet
-    is slot-independent by construction: ``attach`` scatters one row into
-    any free slot later, so prefill never serializes behind a decode step.
-    Under a pod mesh the packet shards its rows over the ``pod`` axis
-    (``sharding.policy.packet_specs``) — the attach-time resharding into
-    the ("pod", "data")-sharded slot slab IS the prefill→decode KV
-    handoff transfer.
+    proposals, prefilled KV caches, fresh per-row policy state).  Rows
+    never mix, so a row is the same at any width: ``admit`` builds one at
+    width 1 and scatters it into its slot.
     """
 
     tokens: Any        # (W, buf_len) slot token buffer rows (padded prompt)
@@ -108,10 +103,9 @@ class ServingFns(NamedTuple):
     request's source tokens (padded like the prompt) for source-drafting
     policies.
 
-    ``admit`` IS ``attach ∘ prefill`` at width 1: the unified engine's
-    admission and the disaggregated engine's prefill-worker path trace the
-    same prefill body and the same scatter, so the two modes are
-    token-identical by construction rather than by test alone.
+    ``prefill`` is the slot-free half of ``admit``, jitted on its own for
+    callers that want a batch of prefilled rows without installing them
+    (``chip_smoke.py`` verifies against its caches).
     """
 
     init: Callable      # (gid) -> SlotBatch (mesh-placed when sharded)
@@ -121,15 +115,7 @@ class ServingFns(NamedTuple):
     step: Callable      # (params, aux, state) -> (state, status (S,) int8)
     evict: Callable     # (state, mask) -> state
     prefill: Callable   # (params, aux, prompts (W,P), plens (W,),
-                        #  srcs (W,P)) -> PrefillPacket — the slot-free
-                        # half of admission, batched to the prefill width
-    attach: Callable    # (state, packet, row, slot, max_new
-                        #  [, tbl_row, write_mask]) -> state — the
-                        # scatter-only half (the KV handoff)
-    attach_many: Callable = None  # (state, packet, rows (W,), slots (W,),
-                        #  max_news (W,), valid (W,)[, tbl_rows (W,P),
-                        #  write_masks (W,P)]) -> state — up to W handoffs
-                        # in ONE dispatch (invalid lanes write nothing)
+                        #  srcs (W,P)) -> PrefillPacket
     paged: Optional["PagedGeometry"] = None  # page-pool geometry (None=dense)
 
 
@@ -454,17 +440,11 @@ class DecodeSession:
 
         def prefill(params, aux, prompts, plens, srcs) -> PrefillPacket:
             """The slot-free half of admission: prefill ``W`` padded
-            prompts in ONE forward and return their handoff packet.
+            prompts in ONE forward and return their packet.
 
-            Per-row computation is identical to the historical batch-1
-            admission prefill (rows never mix — embeddings, attention and
-            the per-row policy init are all row-local), so a packet row
-            attached later is bit-for-bit the state ``admit`` would have
-            scattered directly.  Batching amortizes the per-dispatch host
-            overhead across ``W`` prompts — the disaggregated engine's
-            main throughput lever — and gives prefill its own wide-
-            sequence compute shape, distinct from the decode step's
-            memory-bound block-verify geometry.
+            Rows never mix (embeddings, attention and the per-row policy
+            init are all row-local), so row ``i`` at width ``W`` is
+            bit-for-bit the row ``admit`` builds at width 1.
 
             Per-slot policy state is built fresh here — a packet row never
             inherits a previous occupant's drafter/schedule state — and
@@ -511,15 +491,12 @@ class DecodeSession:
         def attach(state: SlotBatch, packet: PrefillPacket, row, slot,
                    max_new, tbl_row=None, write_mask=None) -> SlotBatch:
             """The scatter-only half of admission: install packet ``row``
-            into slot ``slot`` — the prefill→decode KV handoff.
+            into slot ``slot``.
 
             The packet row is replicated work (its slice never splits the
             data axis); the writes into the slot batch are a global scatter
             constrained back to the slot shardings, so only the data shard
-            owning ``slot`` mutates its rows.  Under a pod mesh the packet
-            rows live on the ``pod`` axis and the scatter reshards them
-            into the ("pod", "data")-split slot slab — the measured
-            device-to-device handoff transfer (launch/dryrun.py).
+            owning ``slot`` mutates its rows.
 
             Under the paged backend the packet rows are dense page-aligned
             workspaces (``PagedBackend.row_init``); ``tbl_row`` ((P,)
@@ -560,37 +537,11 @@ class DecodeSession:
                 policy_state=policy_state,
             )
 
-        def attach_many(state: SlotBatch, packet: PrefillPacket, rows, slots,
-                        max_news, valid, tbl_rows=None,
-                        write_masks=None) -> SlotBatch:
-            """Batched KV handoff: install up to W packet rows into W freed
-            slots in ONE dispatch.  A per-request attach call would hand
-            back the admission dispatch overhead that batching the prefill
-            just amortized — this keeps the whole admission path at O(1)
-            dispatches per worker batch.  ``valid`` masks the short final
-            batch: invalid lanes are skipped entirely (``lax.cond``), so
-            padding writes nothing and the call compiles once at width W.
-            """
-            w_ = rows.shape[0]
-            for i in range(w_):
-                extra = (() if tbl_rows is None
-                         else (tbl_rows[i], write_masks[i]))
-
-                def _install(st, i=i, extra=extra):
-                    return attach(st, packet, rows[i], slots[i],
-                                  max_news[i], *extra)
-
-                state = jax.lax.cond(valid[i], _install, lambda st: st,
-                                     state)
-            return state
-
         def admit(params, aux, state: SlotBatch, slot, prompt, prompt_len,
                   max_new, src, tbl_row=None, write_mask=None) -> SlotBatch:
-            """Unified admission = ``attach ∘ prefill`` at width 1: prefill
-            one padded prompt and scatter it into row ``slot`` in a single
-            jitted call.  Composing the two halves (instead of duplicating
-            their bodies) is what makes the disaggregated engine token-
-            identical to this path by construction."""
+            """Admission = ``attach ∘ prefill`` at width 1: prefill one
+            padded prompt and scatter it into row ``slot`` in a single
+            jitted call."""
             packet = prefill(params, aux, prompt[None],
                              jnp.asarray(prompt_len, I32)[None], src[None])
             return attach(state, packet, jnp.zeros((), I32), slot, max_new,
@@ -679,9 +630,6 @@ class DecodeSession:
                                            donate_argnums=state_dn),
                               evict=jax.jit(evict, donate_argnums=first_dn),
                               prefill=jax.jit(prefill),
-                              attach=jax.jit(attach, donate_argnums=first_dn),
-                              attach_many=jax.jit(attach_many,
-                                                  donate_argnums=first_dn),
                               paged=paged_geom)
 
         rep = NamedSharding(mesh, P())
@@ -691,27 +639,17 @@ class DecodeSession:
                     rep, rep, rep, rep)
         if paged_geom is not None:
             admit_in = admit_in + (rep, rep)  # tbl_row, write_mask
-        # prefill-worker geometry: packet rows shard over the pod axis
-        # (prefill workers own their data-axis slice); the attach scatter
-        # reshards them into the ("pod", "data")-split slot slab — the
-        # sharding-constrained prefill→decode handoff transfer
-        w = max(ecfg.prefill_slots, 1)
+        # a packet's rows are replicated work, as inside admit: derived at
+        # width 1, the batch dim splits no data axis, and the specs hold at
+        # any width
         pkt_struct = jax.eval_shape(
             prefill, _structs(self.params), _structs(self.aux_params),
-            jax.ShapeDtypeStruct((w, ecfg.max_prompt_len), I32),
-            jax.ShapeDtypeStruct((w,), I32),
-            jax.ShapeDtypeStruct((w, ecfg.max_prompt_len), I32))
+            jax.ShapeDtypeStruct((1, ecfg.max_prompt_len), I32),
+            jax.ShapeDtypeStruct((1,), I32),
+            jax.ShapeDtypeStruct((1, ecfg.max_prompt_len), I32))
         pkt_sh = sharding_policy.named(
-            mesh, sharding_policy.packet_specs(cfg, pkt_struct, mesh,
-                                               policy=pol))
-        pre_ax = sharding_policy.prefill_axes(mesh, w)
-        prompts_sh = NamedSharding(mesh, P(pre_ax, None))
-        plens_sh = NamedSharding(mesh, P(pre_ax))
-        attach_in = (slot_sh, pkt_sh, rep, rep, rep)
-        attach_many_in = (slot_sh, pkt_sh, rep, rep, rep, rep)
-        if paged_geom is not None:
-            attach_in = attach_in + (rep, rep)  # tbl_row, write_mask
-            attach_many_in = attach_many_in + (rep, rep)
+            mesh, sharding_policy.state_specs(cfg, pkt_struct, mesh,
+                                              policy=pol))
         return ServingFns(
             init=self._with_mesh(jax.jit(init_slots, in_shardings=(rep,),
                                          out_shardings=slot_sh)),
@@ -729,14 +667,7 @@ class DecodeSession:
                 out_shardings=slot_sh, donate_argnums=first_dn)),
             prefill=self._with_mesh(jax.jit(
                 prefill,
-                in_shardings=(self.param_shardings, aux_sh, prompts_sh,
-                              plens_sh, prompts_sh),
+                in_shardings=(self.param_shardings, aux_sh, rep, rep, rep),
                 out_shardings=pkt_sh)),
-            attach=self._with_mesh(jax.jit(
-                attach, in_shardings=attach_in, out_shardings=slot_sh,
-                donate_argnums=first_dn)),
-            attach_many=self._with_mesh(jax.jit(
-                attach_many, in_shardings=attach_many_in,
-                out_shardings=slot_sh, donate_argnums=first_dn)),
             paged=paged_geom,
         )

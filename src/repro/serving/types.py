@@ -60,16 +60,6 @@ class EngineConfig:
     page_pool_pages: int = 0    # paged backend: physical pages in the pool
                                 # (incl. the trash page); 0 = auto worst
                                 # case (1 + num_slots * pages_per_slot)
-    prefill_slots: int = 0      # disaggregated prefill/decode: width of one
-                                # prefill-worker batch (prompts prefilled
-                                # per forward, handed to decode groups via
-                                # the KV-handoff queue); 0 = unified engine
-                                # (admission prefills inline, the
-                                # historical path)
-    handoff_cap: int = 0        # bound on requests staged for / parked in
-                                # the KV-handoff queue (back-pressure once
-                                # full); 0 = auto (max(2 * num_slots,
-                                # prefill_slots))
     steps_per_sync: int = 1     # fused decode iterations per step()
                                 # dispatch: >1 runs up to this many masked
                                 # iterations in ONE device call (a bounded
@@ -107,25 +97,10 @@ class EngineConfig:
             raise ValueError(
                 f"EngineConfig.max_new_cap must be positive, got "
                 f"{self.max_new_cap}")
-        if self.prefill_slots < 0:
-            raise ValueError(
-                f"EngineConfig.prefill_slots must be >= 0, got "
-                f"{self.prefill_slots} (0 = unified engine)")
-        if self.handoff_cap < 0:
-            raise ValueError(
-                f"EngineConfig.handoff_cap must be >= 0, got "
-                f"{self.handoff_cap} (0 = auto)")
         if self.steps_per_sync < 1:
             raise ValueError(
                 f"EngineConfig.steps_per_sync must be >= 1, got "
                 f"{self.steps_per_sync}")
-        if (self.prefill_slots > 0 and self.handoff_cap > 0
-                and self.handoff_cap < self.prefill_slots):
-            raise ValueError(
-                f"EngineConfig.handoff_cap={self.handoff_cap} is smaller "
-                f"than one prefill batch (prefill_slots="
-                f"{self.prefill_slots}): the prefill worker could never "
-                f"fill a batch — raise the cap or shrink the width")
         if dec is not None and self.max_new_cap > dec.max_new_tokens:
             raise ValueError(
                 f"EngineConfig.max_new_cap={self.max_new_cap} exceeds "

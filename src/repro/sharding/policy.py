@@ -168,39 +168,14 @@ def batch_specs(mesh: Mesh, batch: Pytree) -> Pytree:
         lambda x: data_spec(mesh, x.shape[0], x.ndim), batch)
 
 
-_AUTO = "auto"
-
-
-def prefill_axes(mesh: Mesh, batch_size: int):
-    """Mesh axes a PREFILL-worker batch shards over: the ``pod`` axis alone.
-
-    Disaggregated serving places prefill workers and decode groups on
-    distinct data-axis slices — prefill packets live on the pod axis, the
-    decode slot slab on pod×data, and the attach-time resharding between
-    the two is the measured KV-handoff transfer.  On pod-less meshes (and
-    whenever the width doesn't divide the pod axis) packets replicate,
-    matching the historical batch-1 admission ("single-row prefill is
-    replicated work")."""
-    names = mesh.axis_names
-    if ("pod" in names and mesh.shape["pod"] > 1
-            and batch_size % mesh.shape["pod"] == 0):
-        return ("pod",)
-    return None
-
-
 def cache_specs(cfg: ModelConfig, caches: Pytree, mesh: Mesh,
-                batch_size: int, *, ax=_AUTO) -> Pytree:
+                batch_size: int) -> Pytree:
     """Decode caches: batch over data axes; kv-heads over model where the
     head count divides the axis, otherwise the buffer LENGTH dim shards over
     model (flash-decoding-style sequence sharding: the softmax/PV reductions
     over the sharded length become GSPMD all-reduces, and attn_buf_len pads
-    the buffer to a multiple of 256 so it always divides).
-
-    ``ax`` overrides the batch-dim axes (default: ``batch_axes``) — the
-    prefill-worker packet passes ``prefill_axes`` so its rows live on the
-    pod slice instead of the full data split."""
-    if ax is _AUTO:
-        ax = batch_axes(mesh, batch_size)
+    the buffer to a multiple of 256 so it always divides)."""
+    ax = batch_axes(mesh, batch_size)
     msz = mesh.shape.get("model", 1)
     kv_divides = cfg.num_kv_heads and cfg.num_kv_heads % msz == 0
 
@@ -250,7 +225,7 @@ def cache_specs(cfg: ModelConfig, caches: Pytree, mesh: Mesh,
 def state_specs(cfg: ModelConfig, state: Any, mesh: Mesh, *,
                 batch_size: Optional[int] = None,
                 draft_cfg: Optional[ModelConfig] = None,
-                policy: Any = None, ax=_AUTO) -> Any:
+                policy: Any = None) -> Any:
     """PartitionSpec pytree for a batch-leading decode loop state.
 
     ``state`` is any NamedTuple whose arrays lead with the batch dimension
@@ -284,8 +259,7 @@ def state_specs(cfg: ModelConfig, state: Any, mesh: Mesh, *,
     if draft_cfg is None and policy is not None:
         draft_cfg = getattr(policy.drafter, "cfg", None)
     b = batch_size if batch_size is not None else state.tokens.shape[0]
-    if ax is _AUTO:
-        ax = batch_axes(mesh, b)
+    ax = batch_axes(mesh, b)
 
     def leaf(x) -> P:
         if x.ndim >= 1 and x.shape[0] == b:
@@ -296,7 +270,7 @@ def state_specs(cfg: ModelConfig, state: Any, mesh: Mesh, *,
         dstate = ps.drafter
         if (draft_cfg is not None and isinstance(dstate, dict)
                 and "caches" in dstate):
-            drafter = {k: cache_specs(draft_cfg, v, mesh, b, ax=ax)
+            drafter = {k: cache_specs(draft_cfg, v, mesh, b)
                        if k == "caches"
                        else jax.tree_util.tree_map(leaf, v)
                        for k, v in dstate.items()}
@@ -308,7 +282,7 @@ def state_specs(cfg: ModelConfig, state: Any, mesh: Mesh, *,
     fields = {}
     for name, val in state._asdict().items():
         if name == "caches" and val is not None:
-            fields[name] = cache_specs(cfg, val, mesh, b, ax=ax)
+            fields[name] = cache_specs(cfg, val, mesh, b)
         elif name == "policy_state" and hasattr(val, "drafter"):
             fields[name] = policy_specs(val)
         else:
@@ -330,22 +304,6 @@ def slot_specs(cfg: ModelConfig, slots: Any, mesh: Mesh, *,
     """
     return state_specs(cfg, slots, mesh, batch_size=slots.tokens.shape[0],
                        draft_cfg=draft_cfg, policy=policy)
-
-
-def packet_specs(cfg: ModelConfig, packet: Any, mesh: Mesh, *,
-                 draft_cfg: Optional[ModelConfig] = None,
-                 policy: Any = None) -> Any:
-    """Specs for a prefill worker's handoff packet (``PrefillPacket``).
-
-    Same derivation as ``state_specs`` but the batch (= prefill width) dim
-    shards over ``prefill_axes`` — the pod axis alone — instead of the
-    full pod×data product: prefill workers own their data-axis slice, and
-    attaching a packet row into the ("pod", "data")-sharded slot slab is
-    the prefill→decode KV handoff the dry-run measures.
-    """
-    b = packet.tokens.shape[0]
-    return state_specs(cfg, packet, mesh, batch_size=b, draft_cfg=draft_cfg,
-                       policy=policy, ax=prefill_axes(mesh, b))
 
 
 def data_axis_size(mesh: Mesh) -> int:

@@ -51,13 +51,14 @@ def stack():
     return cfg, params, dec, bundles
 
 
-def _workload(cfg, ecfg, n_per_policy=2, seed=7):
-    """n_per_policy requests per policy in MIX, mixed prompt lengths and
-    budgets — more requests than slots, so groups evict and re-admit."""
+def _workload(cfg, ecfg, n_per_policy=2, seed=7, mix=MIX):
+    """n_per_policy requests per policy in ``mix``, mixed prompt lengths
+    and budgets — more requests than slots, so groups evict and
+    re-admit."""
     rng = np.random.default_rng(seed)
     reqs = []
     for i in range(n_per_policy):
-        for p in MIX:
+        for p in mix:
             plen = int(rng.integers(3, ecfg.max_prompt_len + 1))
             reqs.append(Request(
                 rid=len(reqs), policy=p,
@@ -114,19 +115,26 @@ def _check_all(stack, ecfg, finished, reqs):
         assert f.generated == ref_gen, (r.rid, r.policy)
 
 
-@pytest.mark.parametrize("sched_policy", ["fcfs", "sjf"])
-def test_mixed_policy_engine_token_identical(stack, sched_policy):
-    """5-policy mix, 1 slot per group, 2 requests per policy: every group
-    evicts its first request and admits its second while other groups are
+@pytest.mark.parametrize("sched_policy,mix,n_per_policy", [
+    pytest.param("fcfs", MIX, 2, id="fcfs"),
+    pytest.param("sjf", MIX, 2, id="sjf"),
+    pytest.param("fcfs", ("exact", "topk_tree", "draft_model"), 3,
+                 id="chain-tree-draft"),
+])
+def test_mixed_policy_engine_token_identical(stack, sched_policy, mix,
+                                             n_per_policy):
+    """One slot per policy group, more requests per policy than its slot
+    holds (the 5-policy mix with 2 each; {exact, topk_tree, draft_model}
+    with 3 each): every group evicts and re-admits while other groups are
     mid-decode — admission/eviction interleave across heterogeneous
     policies, and every request still decodes exactly like a lone
     single-policy session run."""
     cfg, params, dec, bundles = stack
-    ecfg = EngineConfig(num_slots=len(MIX), max_prompt_len=6, max_new_cap=12)
+    ecfg = EngineConfig(num_slots=len(mix), max_prompt_len=6, max_new_cap=12)
     eng = ContinuousBatchingEngine(params, cfg, dec, ecfg, bundles=bundles,
-                                   policies={p: 1 for p in MIX})
+                                   policies={p: 1 for p in mix})
     sched = Scheduler(eng, policy=sched_policy)
-    reqs = _workload(cfg, ecfg)
+    reqs = _workload(cfg, ecfg, n_per_policy=n_per_policy, mix=mix)
     for r in reqs:
         sched.submit(r)
     finished = sched.run()
@@ -293,49 +301,20 @@ def test_locality_engine_token_identical(stack):
 
 
 # ---------------------------------------------------------------------------
-# Disaggregated prefill/decode: token identity with the dense references
+# Deadline preemption across policy groups
 # ---------------------------------------------------------------------------
 
 
-def test_disagg_engine_token_identical_across_policies(stack):
-    """The disaggregated engine (dedicated prefill workers + KV-handoff
-    queue) across {exact, topk_tree, draft_model} groups with more
-    requests than slots — admission, eviction and worker prefills all
-    interleave mid-flight, and every stream still matches its
-    single-policy unified-session reference byte-for-byte."""
-    cfg, params, dec, bundles = stack
-    pols = ("exact", "topk_tree", "draft_model")
-    ecfg = EngineConfig(num_slots=3, max_prompt_len=6, max_new_cap=12,
-                        prefill_slots=2, handoff_cap=6)
-    eng = ContinuousBatchingEngine(params, cfg, dec, ecfg, bundles=bundles,
-                                   policies={p: 1 for p in pols})
-    sched = Scheduler(eng)
-    rng = np.random.default_rng(43)
-    reqs = [Request(rid=i, policy=pols[i % 3],
-                    prompt=rng.integers(0, cfg.vocab_size,
-                                        size=int(rng.integers(3, 7))),
-                    max_new=int(rng.integers(4, 13)))
-            for i in range(9)]
-    for r in reqs:
-        sched.submit(r)
-    finished = sched.run()
-    _check_all(stack, ecfg, finished, reqs)
-    assert eng.num_prefill_batches > 0       # admissions used the workers
-    assert all(v == 1 for v in eng.compile_counts().values()), \
-        eng.compile_counts()
-
-
-def test_disagg_preemption_token_identical(stack):
-    """Deadline preemption against the disaggregated engine: full-budget
+def test_preemption_across_groups_token_identical(stack):
+    """Deadline preemption in a two-group engine: full-budget
     low-priority requests occupy every slot, then an urgent already-late
-    request forces an eviction.  The victim requeues through the handoff
-    path and restarts — and every finished stream (victim included) still
+    request forces an eviction.  The victim requeues as a continuation
+    and restarts — and every finished stream (victim included) still
     equals its single-policy reference."""
     cfg, params, dec, bundles = stack
     # max_prompt_len leaves room for prompt + committed tokens: a victim is
     # only feasible while its continuation still fits the admission shape
-    ecfg = EngineConfig(num_slots=2, max_prompt_len=24, max_new_cap=12,
-                        prefill_slots=2, handoff_cap=4)
+    ecfg = EngineConfig(num_slots=2, max_prompt_len=24, max_new_cap=12)
     eng = ContinuousBatchingEngine(
         params, cfg, dec, ecfg, bundles=bundles,
         policies={"exact": 1, "topk_tree": 1})
@@ -654,7 +633,7 @@ def test_group_mesh_divisibility(stack, mesh):
 
 
 # ---------------------------------------------------------------------------
-# Pod mesh ("pod", "data", "model"): disaggregated serving at cluster shape
+# Pod mesh ("pod", "data", "model"): serving at cluster shape
 # (CI `sharded` job with 8 forced host devices; skips elsewhere)
 # ---------------------------------------------------------------------------
 
@@ -670,45 +649,11 @@ def pod_mesh():
 
 
 @pytest.mark.sharded
-def test_disagg_pod_mesh_token_identical(stack, pod_mesh):
-    """Disaggregated engine on the (2,2,2) ("pod","data","model") mesh:
-    prefill packets shard over the pod axis, the decode slot slab over
-    pod×data, and the attach-time resharding is the measured KV handoff.
-    Every stream must still equal its SINGLE-DEVICE single-policy
-    reference — the pod mesh and the handoff move bytes, never tokens."""
-    cfg, params, dec, bundles = stack
-    ecfg = EngineConfig(num_slots=8, max_prompt_len=6, max_new_cap=12,
-                        prefill_slots=4, handoff_cap=8)
-    eng = ContinuousBatchingEngine(
-        params, cfg, dec, ecfg, mesh=pod_mesh, bundles=bundles,
-        policies={"exact": 4, "topk_tree": 4})
-    sched = Scheduler(eng)
-    rng = np.random.default_rng(53)
-    reqs = [Request(rid=i, policy=pol,
-                    prompt=rng.integers(0, cfg.vocab_size,
-                                        size=int(rng.integers(3, 7))),
-                    max_new=int(rng.integers(4, 13)))
-            for i, pol in enumerate(["exact", "topk_tree"] * 6)]
-    for r in reqs:
-        sched.submit(r)
-    finished = sched.run()
-    _check_all(stack, ecfg, finished, reqs)
-    assert eng.num_prefill_batches > 0
-    assert all(v == 1 for v in eng.compile_counts().values()), \
-        eng.compile_counts()
-    # the slot slab genuinely spans the pod axis (pod×data over slots,
-    # model over kv heads) — the cluster shape, not a degenerate layout
-    for g in eng.groups:
-        k = g.state.caches[0]["attn"]["k"]
-        axes = {a for e in k.sharding.spec if e
-                for a in (e if isinstance(e, tuple) else (e,))}
-        assert {"pod", "data", "model"} <= axes, (g.name, k.sharding)
-
-
-@pytest.mark.sharded
 def test_unified_pod_mesh_token_identical(stack, pod_mesh):
-    """The unified engine on the same pod mesh — the equal-device-count
-    baseline of the disaggregation claim stays token-exact too."""
+    """The engine on the (2,2,2) ("pod","data","model") mesh: the slot
+    slab shards over pod×data and the model over kv heads, and every
+    stream still equals its SINGLE-DEVICE single-policy reference — the
+    pod mesh moves bytes, never tokens."""
     cfg, params, dec, bundles = stack
     ecfg = EngineConfig(num_slots=8, max_prompt_len=6, max_new_cap=12)
     eng = ContinuousBatchingEngine(
@@ -724,3 +669,12 @@ def test_unified_pod_mesh_token_identical(stack, pod_mesh):
     for r in reqs:
         sched.submit(r)
     _check_all(stack, ecfg, sched.run(), reqs)
+    assert all(v == 1 for v in eng.compile_counts().values()), \
+        eng.compile_counts()
+    # the slot slab genuinely spans the pod axis (pod×data over slots,
+    # model over kv heads) — the cluster shape, not a degenerate layout
+    for g in eng.groups:
+        k = g.state.caches[0]["attn"]["k"]
+        axes = {a for e in k.sharding.spec if e
+                for a in (e if isinstance(e, tuple) else (e,))}
+        assert {"pod", "data", "model"} <= axes, (g.name, k.sharding)

@@ -242,7 +242,7 @@ def test_preemption_over_the_wire(server):
 
 
 def test_graceful_drain_over_the_wire():
-    """POST /drain against a live (disaggregated) server: 202 immediately,
+    """POST /drain against a live server: 202 immediately,
     readiness flips to 503 "draining", new submissions are refused with
     503, the in-flight stream finishes token-exact, and the listener then
     closes — the whole SIGTERM shutdown path, driven over the wire (the
@@ -253,8 +253,7 @@ def test_graceful_drain_over_the_wire():
     dec = DecodeConfig(max_new_tokens=MAX_NEW, block_k=4)
     eng = ContinuousBatchingEngine(
         params, cfg, dec,
-        EngineConfig(num_slots=2, max_prompt_len=24, max_new_cap=MAX_NEW,
-                     prefill_slots=2, handoff_cap=4))
+        EngineConfig(num_slots=2, max_prompt_len=24, max_new_cap=MAX_NEW))
     srv = HTTPServer(Frontend(Scheduler(eng), max_queue=2), port=0)
     loop = asyncio.new_event_loop()
     thread = threading.Thread(target=loop.run_forever, daemon=True)

@@ -1,21 +1,28 @@
 """Continuous-batching serving engine: token-level equivalence with the
-run-to-completion decoder, slot reuse, mid-flight admission, and the
+run-to-completion decoder, slot reuse, mid-flight admission in any order,
+page-pool back-pressure at admission, host-sync accounting, and the
 compile-once guarantee."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _hyp import given, settings, st
 from conftest import tiny_dense, tiny_rwkv
 from repro.config import DecodeConfig
 from repro.core import decode as D
 from repro.models import model as M
 from repro.serving import (
     ContinuousBatchingEngine,
+    DecodeSession,
     EngineConfig,
+    PagePoolExhausted,
     Request,
     Scheduler,
     aggregate_stats,
+    tracing,
 )
 
 pytestmark = pytest.mark.serving
@@ -272,8 +279,6 @@ def test_single_device_serving_fns_donate_slot_state(donate):
     """Without a mesh the serving functions still donate the slot state
     when the session donates (the default on accelerators), so a step or
     an admission never holds two copies of the KV slab on the chip."""
-    from repro.serving import DecodeSession
-
     cfg = tiny_dense()
     params = M.init(jax.random.PRNGKey(0), cfg)
     sess = DecodeSession(params, cfg, DecodeConfig(max_new_tokens=8,
@@ -286,3 +291,236 @@ def test_single_device_serving_fns_donate_slot_state(donate):
     evict = fns.evict.lower(state, jnp.zeros((2,), bool)).as_text()
     assert step.count("tf.aliasing_output") == (n_state if donate else 0)
     assert evict.count("tf.aliasing_output") == (n_state if donate else 0)
+
+
+# ---------------------------------------------------------------------------
+# Admission in any order, page-pool back-pressure, host-sync accounting
+# ---------------------------------------------------------------------------
+
+ECFG = EngineConfig(num_slots=2, max_prompt_len=6, max_new_cap=10)
+
+
+@pytest.fixture(scope="module")
+def small():
+    cfg = tiny_dense()
+    params = M.init(jax.random.PRNGKey(0), cfg)
+    dec = DecodeConfig(max_new_tokens=10, block_k=4)
+    return cfg, params, dec
+
+
+@pytest.fixture(scope="module")
+def small_engine(small):
+    cfg, params, dec = small
+    return ContinuousBatchingEngine(params, cfg, dec, ECFG)
+
+
+def _workload(cfg, seed, n=6):
+    rng = np.random.default_rng(seed)
+    return [Request(rid=i, arrival=0.0,
+                    prompt=rng.integers(0, cfg.vocab_size,
+                                        size=int(rng.integers(2, 7))),
+                    max_new=int(rng.integers(3, 11)))
+            for i in range(n)]
+
+
+def _drive(eng, reqs):
+    """Admit in list order whenever a slot is free, step until drained."""
+    todo, done = list(reqs), []
+    while todo or eng.has_active():
+        while todo and eng.free_slots():
+            eng.admit(todo.pop(0))
+        done += eng.step()
+    return {f.rid: f for f in done}
+
+
+_IN_ORDER = {}   # workload seed -> streams of the in-order run
+
+
+def _in_order(eng, cfg, seed):
+    if seed not in _IN_ORDER:
+        _IN_ORDER[seed] = _drive(eng, _workload(cfg, seed))
+    return _IN_ORDER[seed]
+
+
+def _check_identical(done, ref):
+    assert sorted(f.rid for f in done) == sorted(ref)
+    for f in done:
+        r = ref[f.rid]
+        np.testing.assert_array_equal(
+            f.tokens, r.tokens,
+            err_msg=f"rid={f.rid}: stream diverged from the reference run")
+        assert f.generated == r.generated, f.rid
+        assert f.invocations == r.invocations, f.rid
+
+
+@settings(max_examples=12, deadline=None)
+@given(seed=st.integers(0, 2**16), order=st.permutations(range(6)),
+       ops=st.lists(st.sampled_from("aas"), min_size=4, max_size=40))
+def test_any_interleaving_token_identical(small, small_engine, seed, order,
+                                          ops):
+    """Hypothesis drives the engine through an ARBITRARY sequence of
+    (a)dmit and decode (s)tep, admitting the requests in a random order,
+    then drains.  Whenever each request enters its slot, and next to
+    whichever others, its stream matches the in-order run bitwise; an
+    admit with no free slot raises and changes nothing."""
+    cfg, _, _ = small
+    eng = small_engine
+    ref = _in_order(eng, cfg, seed)
+    reqs = _workload(cfg, seed)
+    todo, done = [reqs[i] for i in order], []
+    for op in ops:
+        if op == "a" and todo:
+            if not eng.free_slots():
+                with pytest.raises(RuntimeError, match="no free slot"):
+                    eng.admit(todo[0])
+            else:
+                eng.admit(todo.pop(0))
+        elif op == "s" and eng.has_active():
+            done += eng.step()
+    done += _drive(eng, todo).values()
+    _check_identical(done, ref)
+    # the module-scoped engine is reused across examples: geometry never
+    # changes, so nothing may ever recompile
+    assert all(v == 1 for v in eng.compile_counts().values()), \
+        eng.compile_counts()
+
+
+def test_admit_backpressure_page_pool(small):
+    """A paged pool that fits one request: admitting the second raises
+    ``PagePoolExhausted`` before any device work, leaving its slot free
+    and the allocator consistent; once the first retires and releases its
+    pages the second admits, and both streams equal a roomy pool's."""
+    cfg, params, dec = small
+    decp = dec.replace(cache_backend="paged", page_size=8)
+    # each request spans 2 pages (prompt 4 + budget 6 + lookahead 4 over
+    # size-8 pages); pool = 1 trash + 3 allocatable, so ONE admitted
+    # request fits but two cannot coexist
+    eng = ContinuousBatchingEngine(
+        params, cfg, decp, dataclasses.replace(ECFG, page_pool_pages=4))
+    reqs = [Request(rid=i, arrival=0.0, max_new=6,
+                    prompt=np.full((4,), 7 + i, np.int32))
+            for i in range(2)]
+    pages = eng.groups[0].pages
+    eng.admit(reqs[0])
+    with pytest.raises(PagePoolExhausted):
+        eng.admit(reqs[1])
+    assert len(eng.free_slots()) == 1
+    pages.check_invariants()
+    done = []
+    while eng.has_active():
+        done += eng.step()
+    assert [f.rid for f in done] == [0]
+    eng.admit(reqs[1])
+    while eng.has_active():
+        done += eng.step()
+    pages.check_invariants()
+    roomy = ContinuousBatchingEngine(params, cfg, decp, ECFG)
+    _check_identical(done, _drive(roomy, [dataclasses.replace(r)
+                                          for r in reqs]))
+
+
+def test_phase_timers_and_overlap(small):
+    """The engine's spans attribute host time per phase (admission, step
+    dispatch, status pull and retirement), and with two active groups each
+    step harvests group A while group B's device step is still in flight —
+    ``num_overlap_harvests`` counts exactly stepped_groups - 1 per step."""
+    cfg, params, dec = small
+    eng = ContinuousBatchingEngine(params, cfg, dec, ECFG,
+                                   policies={"exact": 1, "topk": 1})
+    sched = Scheduler(eng)
+    before = tracing.totals()
+    rng = np.random.default_rng(5)
+    for i in range(6):
+        sched.submit(Request(
+            rid=i, arrival=0.0, policy=("exact", "topk")[i % 2],
+            prompt=rng.integers(0, cfg.vocab_size,
+                                size=int(rng.integers(2, 7))),
+            max_new=int(rng.integers(3, 11))))
+    finished = sched.run()
+    assert len(finished) == 6
+    spent = {name: t["seconds_total"]
+             - before.get(name, {}).get("seconds_total", 0.0)
+             for name, t in tracing.totals().items()}
+    assert spent["serve.engine.admit"] > 0.0
+    assert spent["serve.engine.dispatch"] > 0.0
+    assert spent["serve.engine.sync"] + spent["serve.engine.harvest"] > 0.0
+    # both groups were active together at least once -> overlapped harvest
+    assert eng.num_overlap_harvests > 0
+    # overlap never exceeds (groups - 1) per step taken
+    assert eng.num_overlap_harvests <= eng.num_steps
+
+
+def test_one_fused_sync_per_group_step_preserved(small, small_engine):
+    """Admission costs ZERO device->host syncs; each group step costs
+    exactly one fused status pull, plus one harvest pull per finishing
+    group."""
+    cfg, _, _ = small
+    eng = small_engine
+    todo, done = _workload(cfg, seed=3, n=4), []
+    before = eng.num_host_syncs
+    steps = pulls = 0
+    while todo or eng.has_active():
+        while todo and eng.free_slots():
+            n = eng.num_host_syncs
+            eng.admit(todo.pop(0))
+            assert eng.num_host_syncs == n   # admission is sync-free
+        got = eng.step()
+        steps += 1                           # single group -> 1 status pull
+        pulls += 1 if got else 0             # + 1 harvest pull if finished
+        done += got
+    assert eng.num_host_syncs - before == steps + pulls
+    assert len(done) == 4
+
+
+def test_windowed_decode_token_identical(small, small_engine):
+    """``steps_per_sync > 1`` fuses up to K decode iterations into one
+    dispatch (a bounded while_loop over the same traced step body, early-
+    exiting when any row finishes).  Streams must stay bitwise identical
+    to per-step syncing, including per-request ``invocations`` (the early
+    exit surfaces finished rows at the same iteration per-step syncing
+    would)."""
+    cfg, params, dec = small
+    reqs = _workload(cfg, seed=11, n=8)
+    ref = _drive(small_engine, [dataclasses.replace(r) for r in reqs])
+    eng = ContinuousBatchingEngine(
+        params, cfg, dec, dataclasses.replace(ECFG, steps_per_sync=3))
+    sched = Scheduler(eng)
+    for r in reqs:
+        sched.submit(dataclasses.replace(r))
+    _check_identical(sched.run(), ref)
+    assert all(v == 1 for v in eng.compile_counts().values())
+
+
+def test_prefill_rows_match_admit(small):
+    """``prefill`` at width W builds, row for row, bitwise the state that
+    ``admit`` installs at width 1: caches, first-block proposals, policy
+    state, token buffer and prompt length.  This batch-size invariance is
+    what ``chip_smoke.py``'s cached verify relies on when it prefills a
+    whole batch of prompts at once."""
+    cfg, params, dec = small
+    w, plen = 3, ECFG.max_prompt_len
+    fns = DecodeSession(params, cfg, dec).serving_fns(
+        dataclasses.replace(ECFG, num_slots=w))
+    rng = np.random.default_rng(17)
+    prompts = np.zeros((w, plen), np.int32)
+    plens = rng.integers(2, plen + 1, size=w).astype(np.int32)
+    for i, p in enumerate(plens):
+        prompts[i, :p] = rng.integers(0, cfg.vocab_size, size=p)
+    packet = fns.prefill(params, {}, jnp.asarray(prompts),
+                         jnp.asarray(plens), jnp.asarray(prompts))
+    state = fns.init(jnp.zeros((), jnp.int32))
+    for i in range(w):
+        state = fns.admit(params, {}, state, jnp.asarray(i, jnp.int32),
+                          jnp.asarray(prompts[i]),
+                          jnp.asarray(plens[i], jnp.int32),
+                          jnp.asarray(ECFG.max_new_cap, jnp.int32),
+                          jnp.asarray(prompts[i]))
+    np.testing.assert_array_equal(np.asarray(state.prompt_len),
+                                  np.asarray(packet.prompt_len))
+    for field in ("tokens", "proposals", "caches", "policy_state"):
+        slot_leaves = jax.tree_util.tree_leaves(getattr(state, field))
+        row_leaves = jax.tree_util.tree_leaves(getattr(packet, field))
+        assert len(slot_leaves) == len(row_leaves), field
+        for a, b in zip(slot_leaves, row_leaves):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                          err_msg=field)
